@@ -9,6 +9,7 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import env_fingerprint
 
 from . import (
@@ -86,6 +87,7 @@ def main() -> None:
                          "benchmarks.common.quick — smaller graphs, fewer "
                          "sweep points)")
     args = ap.parse_args()
+    enable_compile_cache()
     selected = set(args.suite or [])
     if args.only:
         selected.add(args.only)

@@ -56,7 +56,7 @@ BUCKET_HELPERS = {
     "search_steps",
     "candidate_tiles",
     "_pick_tiles",
-    "_clamp_tiles",
+    "check_tiles",
     "pad_to_bucket",
 }
 

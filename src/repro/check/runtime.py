@@ -104,9 +104,7 @@ def _default_kernel_table():
     try:
         from repro.kernels.triangle_count import triangle_count as _tc
 
-        jitted["pallas_run_count"] = _tc._run_count
-        jitted["pallas_run_per_node"] = _tc._run_per_node
-        jitted["pallas_run_support"] = _tc._run_support
+        jitted["pallas_run"] = _tc._run
     except Exception:  # pallas layer optional at audit time
         pass
     lru = {}

@@ -151,18 +151,7 @@ def build_gnn_dryrun(
         params_sds = jax.eval_shape(lambda k: model_mod.init_params(k, cfg), jax.random.PRNGKey(0))
 
         if shardmap_psum:
-            import inspect
-
-            try:  # jax ≥ 0.6 exports shard_map at top level
-                from jax import shard_map
-            except ImportError:  # jax 0.4.x keeps it under jax.experimental
-                from jax.experimental.shard_map import shard_map
-            # jax renamed check_rep → check_vma; pass whichever exists
-            _ckw = (
-                "check_vma"
-                if "check_vma" in inspect.signature(shard_map).parameters
-                else "check_rep"
-            )
+            from jax import shard_map
 
             def shard_loss(p, feat, pos, src, dst, labels):
                 out = model_mod.apply(
@@ -175,7 +164,7 @@ def build_gnn_dryrun(
                 mesh=mesh,
                 in_specs=(P(), P(), P(), P(all_axes), P(all_axes), P()),
                 out_specs=P(),
-                **{_ckw: False},
+                check_vma=False,
             )
 
             def step(params, opt_state, feat, pos, edge_src, edge_dst, labels):

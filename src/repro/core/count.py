@@ -53,6 +53,9 @@ __all__ = [
     "count_triangles",
     "per_node_triangles",
     "bucketize_edges",
+    "gather_elements",
+    "gather_windows",
+    "gather_panel_rows",
     "gather_panels",
     "gather_panels_arrays",
     "panel_intersect_count",
@@ -279,6 +282,64 @@ def bucketize_edges(
     return buckets
 
 
+LANES = 128  # a TPU vector register row: the unit the window gather moves
+
+
+def gather_elements(col: jax.Array, base: jax.Array, length: jax.Array, width: int):
+    """Rows ``col[base : base + length]``, −1 padded to ``width`` — ``(B, width)``.
+
+    One single-element gather of ``B·width`` slots.  The panel gather of
+    every backend but the TPU (see :func:`gather_panel_rows`).
+    """
+    lane = jnp.arange(width, dtype=jnp.int32)
+    idx = jnp.clip(base[:, None] + lane[None, :], 0, col.shape[0] - 1)
+    return jnp.where(lane[None, :] < length[:, None], col[idx], -1)
+
+
+def gather_windows(col: jax.Array, base: jax.Array, length: jax.Array, width: int):
+    """Rows ``col[base : base + length]``, −1 padded to ``width`` — ``(B, width)``.
+
+    Each window is fetched as ``k`` whole 128-lane rows of ``col`` viewed
+    as ``(·, 128)`` — a ``(B, k)`` row-index gather, the embedding-lookup
+    form — and then shifted left by ``base % 128`` with a seven-step
+    barrel shift of static lane rolls.  Lanes at or past ``length`` read
+    −1.  The panel gather on a TPU (see :func:`gather_panel_rows`).
+    Fetching the rows as one ``(k, 128)`` dynamic slice per window
+    compiles far faster but ran 2.5–10× slower on a v5e (PERF.md).
+    """
+    k = -(-width // LANES) + 1   # rows covering any window of `width` slots
+    m = col.shape[0]
+    n_rows = -(-m // LANES) + k
+    rows2d = jnp.pad(col, (0, n_rows * LANES - m), constant_values=-1)
+    rows2d = rows2d.reshape(n_rows, LANES)
+    base = jnp.clip(base, 0, m)
+    row = base // LANES
+    x = rows2d[row[:, None] + jnp.arange(k, dtype=row.dtype)[None, :]]
+    x = x.reshape(base.shape[0], k * LANES)
+    shift = base % LANES
+    for bit in range(LANES.bit_length() - 1):
+        moved = jnp.roll(x, -(1 << bit), axis=1)
+        x = jnp.where(((shift >> bit) & 1)[:, None] == 1, moved, x)
+    lane = jnp.arange(width, dtype=jnp.int32)
+    return jnp.where(lane[None, :] < length[:, None], x[:, :width], -1)
+
+
+def gather_panel_rows(col: jax.Array, base: jax.Array, length: jax.Array, width: int):
+    """The panel gather, chosen by the platform the program is lowered for.
+
+    A TPU runs :func:`gather_windows`; every other backend runs
+    :func:`gather_elements`.  Both return the same array.  On a TPU v5e
+    XLA's single-element gather moves a few times 1e7 slots per second,
+    4–80× slower than whole-row slices; on XLA's CPU backend it is the
+    faster form (``benchmarks/gather_probe.py``; PERF.md).
+    """
+    return jax.lax.platform_dependent(
+        col, base, length,
+        tpu=functools.partial(gather_windows, width=width),
+        default=functools.partial(gather_elements, width=width),
+    )
+
+
 @functools.partial(jax.jit, static_argnames=("width",))
 def gather_panels(csr: OrientedCSR, edge_idx: jax.Array, width: int):
     """Gather fixed-width neighbor panels for a bucket of edges.
@@ -296,18 +357,10 @@ def gather_panels(csr: OrientedCSR, edge_idx: jax.Array, width: int):
     safe = jnp.maximum(edge_idx, 0)
     u = csr.src[safe]
     v = csr.col[safe]
-    lane = jnp.arange(width, dtype=jnp.int32)
-    m_dir = csr.col.shape[0]
-
-    def panel(base, length):
-        idx = jnp.clip(base[:, None] + lane[None, :], 0, m_dir - 1)
-        vals = csr.col[idx]
-        return jnp.where(lane[None, :] < length[:, None], vals, -1)
-
     a_len = jnp.where(valid, csr.out_degree[u], 0)
     b_len = jnp.where(valid, csr.out_degree[v], 0)
-    a = panel(csr.row_offsets[u], a_len)
-    b = panel(csr.row_offsets[v], b_len)
+    a = gather_panel_rows(csr.col, csr.row_offsets[u], a_len, width)
+    b = gather_panel_rows(csr.col, csr.row_offsets[v], b_len, width)
     return a, b, a_len, b_len
 
 
@@ -326,18 +379,10 @@ def gather_panels_arrays(row_offsets, col, out_degree, u, v, width: int):
     valid = (u >= 0) & (v >= 0)
     safe_u = jnp.maximum(u, 0)
     safe_v = jnp.maximum(v, 0)
-    lane = jnp.arange(width, dtype=jnp.int32)
-    m_dir = col.shape[0]
-
-    def panel(base, length):
-        idx = jnp.clip(base[:, None] + lane[None, :], 0, m_dir - 1)
-        vals = col[idx]
-        return jnp.where(lane[None, :] < length[:, None], vals, -1)
-
     a_len = jnp.where(valid, out_degree[safe_u], 0)
     b_len = jnp.where(valid, out_degree[safe_v], 0)
-    a = panel(row_offsets[safe_u], a_len)
-    b = panel(row_offsets[safe_v], b_len)
+    a = gather_panel_rows(col, row_offsets[safe_u], a_len, width)
+    b = gather_panel_rows(col, row_offsets[safe_v], b_len, width)
     return a, b, a_len, b_len
 
 

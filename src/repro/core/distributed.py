@@ -22,16 +22,12 @@ preprocessing fraction exactly as the paper's §III-E does.
 from __future__ import annotations
 
 import functools
-import inspect
 from typing import Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:  # jax ≥ 0.6 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # jax 0.4.x keeps it under jax.experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .count import _batched_contains, _batched_search, segmented_int32_sum
@@ -50,16 +46,6 @@ __all__ = [
     "count_triangles_distributed_panel",
     "oriented_csr_from_slabs",
 ]
-
-# jax renamed shard_map's replication-check kwarg; the support kernel's
-# all_gather defeats static replication inference either way, so pass
-# whichever this version accepts with False
-_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in inspect.signature(shard_map).parameters
-    else "check_rep"
-)
-
 
 def stripe_edges(csr: OrientedCSR, n_shards: int, shorter_side: bool = False):
     """Round-robin stripe directed edges into ``(n_shards, e_per_shard)``.
@@ -335,7 +321,7 @@ def striped_workload_fn(
         mesh=mesh,
         in_specs=(edge_spec, edge_spec, rep, rep, rep, rep),
         out_specs=out_spec,
-        **{_CHECK_KW: False},
+        check_vma=False,
     )
     return jax.jit(f)
 

@@ -1249,8 +1249,10 @@ def choose_method(
     * a multi-device mesh always wins — the §III-E striping scales and is
       exact regardless of skew;
     * on TPU, panels that fit the largest bucket go to the Pallas kernel
-      (equality tiles saturate the VPU; the texture-cache role is played
-      by explicit VMEM staging);
+      (equality tiles keep the VPU lanes busy; the texture-cache role is
+      played by explicit VMEM staging) — it compiles natively at every
+      bucket width and its ×4 extensions, which ``tests/test_tpu_compile.py``
+      checks against a described v5e chip;
     * low degree + low skew favors the jnp panel schedule (padding waste
       bounded, O(L²) constant small);
     * heavy tails — Kronecker-style skew — favor ``wedge_bsearch``, whose

@@ -2,10 +2,11 @@
 
 The paper tunes its CUDA kernel by sweeping threads-per-edge warp sizes
 per graph and keeping the fastest; the TPU analogue of that knob is the
-Pallas kernel's ``(block_edges, TLv)`` tile pair (edge-block height ×
-v-panel tile width).  :func:`autotune_tiles` reruns exactly that sweep —
-time every admissible candidate on synthetic panels of the *shape* being
-tuned (shapes, not data, determine kernel runtime) and keep the argmin —
+Pallas kernel's ``(block_edges, TLv)`` tile pair (edges per grid step
+on the lane axis × v-panel tile height).  :func:`autotune_tiles` reruns
+exactly that sweep — time every admissible candidate on synthetic panels
+of the *shape* being tuned (shapes, not data, determine kernel runtime)
+and keep the argmin —
 and :class:`TileCache` persists the winners in a versioned on-disk JSON
 so the sweep is paid once per shape per machine, not once per run.
 
@@ -49,13 +50,11 @@ __all__ = [
     "CACHE_VERSION",
 ]
 
-CACHE_VERSION = 1
+# 2: tiles are (edges-per-step on the lane axis, TLv); version-1 picks
+# (TB in sublanes) are refused by the kernels and must not be served
+CACHE_VERSION = 2
 
-# the Pallas kernel's VMEM ceiling for the eq cube (elements) — candidates
-# are generated under the same budget `_pick_tiles` respects
-_VMEM_BUDGET = 1 << 21
-
-_TB_LADDER = (8, 16, 32, 64, 128, 256)
+_TB_LADDER = (128, 256, 512, 1024, 2048)
 _TLV_LADDER = (128, 256, 512)
 
 
@@ -81,24 +80,29 @@ def shape_key(n_edges: int, lu: int, lv: int) -> str:
 def candidate_tiles(n_edges: int, lu: int, lv: int) -> list[TileConfig]:
     """The §III-D5 sweep grid for one panel shape.
 
-    Every (TB, TLv) with TB in the pow2 ladder (clamped to the edge
-    count), TLv in the lane-width ladder (clamped to Lv), whose equality
-    cube fits the VMEM budget; the static heuristic's pick is always
-    included so tuning can never do worse than not tuning.
+    Every (TB, TLv) with TB in the lane-multiple ladder (up to the first
+    rung covering the edge count) and TLv in the lane-width ladder plus
+    the whole v width, that the kernel's own :func:`check_tiles` admits
+    for every family member — so no candidate can fail to compile.  The
+    static heuristic's pick is always included so tuning can never do
+    worse than not tuning.
     """
-    from repro.kernels.triangle_count.triangle_count import _pick_tiles
+    from repro.kernels.triangle_count.triangle_count import (
+        KINDS,
+        _pick_tiles,
+        check_tiles,
+    )
 
     n_edges = max(int(n_edges), 1)
-    seen: dict[tuple[int, int], None] = {}
+    seen: dict[tuple[int, int], None] = {_pick_tiles(n_edges, lu, lv): None}
     for tb in _TB_LADDER:
-        if tb > n_edges and tb != next_pow2(n_edges):
-            continue
-        tb_c = min(tb, n_edges)
-        for tlv in _TLV_LADDER:
-            tlv_c = min(tlv, lv)
-            if tb_c * lu * tlv_c <= _VMEM_BUDGET:
-                seen[(tb_c, tlv_c)] = None
-    seen[_pick_tiles(n_edges, lu, lv)] = None
+        for tlv in (*_TLV_LADDER, max(int(lv), 1)):
+            try:
+                resolved = [check_tiles((tb, tlv), n_edges, lu, lv, k) for k in KINDS]
+            except ValueError:
+                continue
+            tb_r, _, tlv_r = resolved[0]
+            seen.setdefault((tb_r, tlv_r), None)
     return [TileConfig(tb, tlv) for tb, tlv in seen]
 
 

@@ -17,13 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU scratch memory spaces; the interpreter accepts them too
-    from jax.experimental.pallas import tpu as pltpu
-
-    _SCRATCH = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _SCRATCH = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention_pallas"]
 
@@ -121,9 +115,9 @@ def flash_attention_pallas(
         out_specs=pl.BlockSpec((1, tq, d), lambda h, i, j: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * hq, sq, d), q.dtype),
         scratch_shapes=[
-            _SCRATCH((tq, 1), jnp.float32),
-            _SCRATCH((tq, 1), jnp.float32),
-            _SCRATCH((tq, d), jnp.float32),
+            pltpu.VMEM((tq, 1), jnp.float32),
+            pltpu.VMEM((tq, 1), jnp.float32),
+            pltpu.VMEM((tq, d), jnp.float32),
         ],
         interpret=interpret,
     )(qf, kf, vf)

@@ -31,6 +31,7 @@ import time
 from repro import obs
 from repro.analytics import graph_report
 from repro.core.engine import METHODS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.count import (
     add_source_arguments,
     add_trace_argument,
@@ -65,6 +66,7 @@ def main() -> None:
         ap.error("--top-k must be non-negative")
 
     log = functools.partial(print, file=sys.stderr) if args.json else print
+    enable_compile_cache()
     with obs.trace_to_file(args.trace, meta={"cli": "analyze"}):
         _run_analyze(args, log)
     if args.trace:

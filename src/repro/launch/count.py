@@ -35,6 +35,7 @@ import time
 import numpy as np
 
 from repro import obs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core import TriangleCounter, count_triangles_numpy
 from repro.core.engine import METHODS
 from repro.graphs import GRAPH_GENERATORS, graph_stats
@@ -102,16 +103,20 @@ def add_source_arguments(ap: argparse.ArgumentParser) -> None:
     ap.add_argument("--seed", type=int, default=0)
 
 
-def resolve_graph(args, log=print):
+def resolve_graph(args, log=print, edges=None):
     """Resolve the CLI's graph source to ``(graph, source_info)``.
 
     ``graph`` is a canonical edge array (generators) or a cached/ingested
     ``CSRGraph`` (``--input`` / ``--dataset``) — both are accepted
     directly by :class:`repro.core.TriangleCounter`.  ``source_info`` is a
     JSON-ready provenance dict (ingest stats, cache hit, expected count).
+    ``edges``, when given, is the generator's output already built by the
+    caller for the same flags; it is used instead of generating again.
     """
     if args.input is not None and args.dataset is not None:
         raise SystemExit("--input and --dataset are mutually exclusive")
+    if edges is not None and (args.input is not None or args.dataset is not None):
+        raise ValueError("a pre-built edge array stands only for a generator source")
     storage = getattr(args, "storage", "flat")
     order = getattr(args, "order", None)
     if order is not None and storage != "compressed":
@@ -159,7 +164,8 @@ def resolve_graph(args, log=print):
             expected_triangles=ds.triangles if real else None,
         )
     else:
-        edges = build_graph(args)
+        if edges is None:
+            edges = build_graph(args)
         info = dict(source="generator", generator=args.generator,
                     ingest=None, expected_triangles=None)
         st = graph_stats(edges)
@@ -178,7 +184,13 @@ def resolve_graph(args, log=print):
     return csr, info
 
 
-def main() -> None:
+def main(argv=None, *, edges=None) -> dict:
+    """Run the CLI on ``argv`` (default ``sys.argv``); return the JSON record.
+
+    ``edges`` is an optional pre-built generator output for the same
+    flags (see :func:`resolve_graph`), so a caller that already holds a
+    large generated graph does not build it twice.
+    """
     ap = argparse.ArgumentParser()
     add_source_arguments(ap)
     ap.add_argument("--method", default=None, choices=list(METHODS),
@@ -208,7 +220,7 @@ def main() -> None:
                     help="print one machine-readable JSON object on stdout "
                          "(progress lines go to stderr)")
     add_trace_argument(ap)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.max_wedge_chunk is not None and args.max_wedge_chunk < 1:
         ap.error("--max-wedge-chunk must be a positive number of wedge slots")
     if args.distributed:
@@ -221,16 +233,18 @@ def main() -> None:
         args.method = "auto"
 
     log = functools.partial(print, file=sys.stderr) if args.json else print
+    enable_compile_cache()
     with obs.trace_to_file(args.trace, meta={"cli": "count"}):
-        _run_count(args, log)
+        out = _run_count(args, log, edges)
     if args.trace:
         log(f"trace written to {args.trace}")
+    return out
 
 
-def _run_count(args, log) -> None:
+def _run_count(args, log, edges=None) -> dict:
     t_build0 = time.time()
     with obs.span("ingest", cat="io"):
-        graph, info = resolve_graph(args, log=log)
+        graph, info = resolve_graph(args, log=log, edges=edges)
     build_s = time.time() - t_build0
 
     mesh = None
@@ -311,28 +325,29 @@ def _run_count(args, log) -> None:
         log(f"avg clustering = {clustering_summary['average']:.4f} "
             f"({cluster_s*1e3:.1f} ms)")
 
+    out = dict(
+        triangles=t,
+        method=es.method,
+        resolved_method=es.resolved_method,
+        stats=dict(
+            n_chunks=es.n_chunks,
+            peak_wedge_buffer=es.peak_wedge_buffer,
+            wedge_budget=es.wedge_budget,
+            total_wedges=es.total_wedges,
+            n_directed_edges=es.n_directed_edges,
+            fallback_reason=es.fallback_reason,
+            timings=es.timings,
+        ),
+        counters=obs.metrics_snapshot()["counters"],
+        graph=info.get("graph"),
+        source={k: v for k, v in info.items() if k != "graph"},
+        timings_s=dict(build=build_s, count=dt, baseline=baseline_s),
+        transitivity=trans,
+        clustering=clustering_summary,
+    )
     if args.json:
-        out = dict(
-            triangles=t,
-            method=es.method,
-            resolved_method=es.resolved_method,
-            stats=dict(
-                n_chunks=es.n_chunks,
-                peak_wedge_buffer=es.peak_wedge_buffer,
-                wedge_budget=es.wedge_budget,
-                total_wedges=es.total_wedges,
-                n_directed_edges=es.n_directed_edges,
-                fallback_reason=es.fallback_reason,
-                timings=es.timings,
-            ),
-            counters=obs.metrics_snapshot()["counters"],
-            graph=info.get("graph"),
-            source={k: v for k, v in info.items() if k != "graph"},
-            timings_s=dict(build=build_s, count=dt, baseline=baseline_s),
-            transitivity=trans,
-            clustering=clustering_summary,
-        )
         print(json.dumps(out, indent=None, sort_keys=True))
+    return out
 
 
 if __name__ == "__main__":

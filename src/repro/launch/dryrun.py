@@ -1,5 +1,12 @@
+"""CPU-only tool: lower and compile a model cell on 512 fake CPU devices.
+
+It forces the host platform's device count before JAX starts, so it
+never touches an accelerator; roofline terms are computed against the
+peaks of the pod it models (:data:`TARGET_KIND`), not of the CPU.
+"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 # ^ MUST precede every other import: jax locks the device count on first
 # backend initialization.  This module is the ONLY place the 512-device
@@ -12,6 +19,8 @@ import subprocess
 import sys
 import time
 
+TARGET_KIND = "TPU v5 lite"   # the modeled production pod (v5e)
+
 
 def run_cell(arch: str, shape: str, multi_pod: bool, variant: str = "baseline") -> dict:
     """Lower + compile one (arch × shape × mesh) cell; return the record."""
@@ -20,7 +29,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, variant: str = "baseline") 
     from repro.configs import get_arch
     from repro.launch.flops import trace_cost
     from repro.launch.mesh import make_production_mesh
-    from repro.launch.roofline import roofline_terms
+    from repro.launch.roofline import hardware_for, roofline_terms
 
     mesh = make_production_mesh(multi_pod=multi_pod)
     chips = math.prod(mesh.devices.shape)
@@ -43,7 +52,8 @@ def run_cell(arch: str, shape: str, multi_pod: bool, variant: str = "baseline") 
             }
     except Exception as e:  # CPU backend may not implement it
         mem = {"unavailable": str(e)}
-    report = roofline_terms(compiled, chips, spec.model_flops, walker_cost=walker)
+    report = roofline_terms(compiled, chips, spec.model_flops,
+                            hardware_for(TARGET_KIND), walker_cost=walker)
     rec = {
         "arch": arch,
         "shape": shape,

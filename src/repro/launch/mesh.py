@@ -7,15 +7,12 @@ module never touches JAX device state — the dry-run driver must set
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax ≥ 0.5 has explicit axis types
-    from jax.sharding import AxisType
 
-    def _mesh(shape, axes):
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-except ImportError:  # jax 0.4.x: every axis is implicitly auto
-    def _mesh(shape, axes):
-        return jax.make_mesh(shape, axes)
+def _mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
 
 __all__ = ["make_production_mesh", "make_local_mesh", "DATA_AXES", "ALL_AXES"]
 

@@ -1,7 +1,8 @@
 """Roofline-term extraction from compiled (AOT) artifacts.
 
-Hardware model: TPU v5e — 197 TFLOP/s bf16 per chip, 819 GB/s HBM,
-~50 GB/s/link ICI.
+Hardware peaks come from :data:`PEAKS`, keyed by the ``device_kind``
+JAX reports; a device missing from the table is an error, never a
+default.
 
 * compute   = HLO_FLOPs_total   / (chips · peak)
 * memory    = HLO_bytes_total   / (chips · HBM_bw)
@@ -19,17 +20,40 @@ from __future__ import annotations
 import dataclasses
 import re
 
-__all__ = ["HW", "Hardware", "collective_stats", "roofline_terms", "RooflineReport"]
+__all__ = [
+    "PEAKS",
+    "Hardware",
+    "hardware_for",
+    "collective_stats",
+    "roofline_terms",
+    "RooflineReport",
+]
 
 
 @dataclasses.dataclass(frozen=True)
 class Hardware:
-    peak_flops: float = 197e12   # bf16 FLOP/s per chip
-    hbm_bw: float = 819e9        # B/s per chip
-    ici_bw: float = 50e9         # B/s per link
+    peak_flops: float   # bf16 FLOP/s per chip
+    hbm_bw: float       # B/s per chip
+    ici_bw: float       # B/s per link
 
 
-HW = Hardware()
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM,
+# 1,600 Gbit/s of chip-to-chip interconnect (~50 GB/s per link of four)
+PEAKS = {
+    "TPU v5 lite": Hardware(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def hardware_for(device_kind: str) -> Hardware:
+    """The peaks of one chip kind; raises for a kind not in :data:`PEAKS`."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak table for device kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)}"
+        ) from None
+
 
 _COLLECTIVE_OPS = (
     "all-reduce",
@@ -98,7 +122,7 @@ class RooflineReport:
     collective_bytes_per_device: float
     model_flops: float
     collectives: dict
-    hw: Hardware = HW
+    hw: Hardware
     xla_flops_per_device: float = 0.0   # raw cost_analysis (loop bodies ×1)
     xla_bytes_per_device: float = 0.0
     by_prim: dict = dataclasses.field(default_factory=dict)
@@ -162,9 +186,10 @@ class RooflineReport:
 
 
 def roofline_terms(
-    compiled, chips: int, model_flops: float, walker_cost: dict | None = None
+    compiled, chips: int, model_flops: float, hw: Hardware,
+    walker_cost: dict | None = None,
 ) -> RooflineReport:
-    """Build the report.
+    """Build the report against ``hw`` (see :func:`hardware_for`).
 
     ``walker_cost`` (from :mod:`repro.launch.flops`) provides loop-aware
     GLOBAL flops/bytes; per-device = global / chips.  The raw
@@ -191,6 +216,7 @@ def roofline_terms(
         collective_bytes_per_device=float(stats["total_bytes"]),
         model_flops=model_flops,
         collectives=stats,
+        hw=hw,
         xla_flops_per_device=xla_flops,
         xla_bytes_per_device=xla_bytes,
         by_prim=by_prim,

@@ -42,6 +42,7 @@ import numpy as np
 from repro import obs
 from repro.core import TriangleCounter
 from repro.graphs import STREAM_GENERATORS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.count import (
     add_source_arguments,
     add_trace_argument,
@@ -127,6 +128,7 @@ def main() -> None:
         ap.error("--resume requires --snapshot-dir")
 
     log = functools.partial(print, file=sys.stderr) if args.json else print
+    enable_compile_cache()
     with obs.trace_to_file(args.trace, meta={"cli": "serve_graph"}):
         _run_serve(args, log)
     if args.trace:

@@ -1,15 +1,16 @@
 """Deterministic fallback for the slice of the `hypothesis` API we use.
 
-The container image does not ship `hypothesis`; rather than lose the
-property tests entirely, this stub replays each ``@given`` test over a
-fixed number of deterministically seeded random draws.  It implements
+The real `hypothesis` package is now installed where the tests run, so
+the test modules import it and this stub is used only where it is
+missing.  There it replays each ``@given`` test over a fixed number of
+deterministically seeded random draws.  It implements
 only what the test files need: ``given``, ``settings`` and the
 ``integers`` / ``lists`` / ``tuples`` / ``sampled_from`` / ``randoms`` /
 ``composite`` strategies.  No shrinking, no example database — failures
 print the drawn values so they can be replayed by seed.
 
-When the real `hypothesis` is installed (e.g. in CI), the test modules
-import it instead and this file is inert.
+Unlike the real package, the stub ignores ``deadline``; property tests
+that compile JAX code pass ``deadline=None`` so both behave alike.
 """
 from __future__ import annotations
 

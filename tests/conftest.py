@@ -1,6 +1,11 @@
 """Shared fixtures. NOTE: never set --xla_force_host_platform_device_count
 here — smoke tests and benches must see the real single-CPU world; only
 ``repro.launch.dryrun`` (and subprocess helpers below) fake a topology.
+
+The suite is a CPU-only tool: it runs with ``JAX_PLATFORMS=cpu`` and the
+Pallas kernels in interpret mode.  ``run_multidevice`` starts children
+that use JAX, which is only sound off the chip (a chip belongs to one
+process), so those children are pinned to the CPU backend.
 """
 import os
 import subprocess
@@ -17,6 +22,7 @@ def run_multidevice(code: str, n_devices: int = 8, timeout: int = 480) -> str:
     """Run a python snippet in a subprocess with N fake CPU devices."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run(
         [sys.executable, "-c", code],

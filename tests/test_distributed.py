@@ -49,8 +49,10 @@ from repro.distributed.sharding import make_param_shardings, spec_for
 from repro.models import transformer as tfm
 from repro.data import lm_batch
 
+from repro.launch.mesh import make_local_mesh
+
 cfg = REGISTRY["qwen2-1.5b"].smoke_config()
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_local_mesh(2, 4)  # auto-sharded axes, as the launchers build them
 params = tfm.init_params(jax.random.PRNGKey(0), cfg)
 step_fn, opt_init = make_lm_train_step(cfg, accum=2)
 opt = opt_init(params)
@@ -83,14 +85,8 @@ print("OK", float(m2["loss"]))
 @pytest.mark.slow
 def test_compressed_psum_and_error_feedback():
     out = run_multidevice("""
-import inspect, jax, jax.numpy as jnp, numpy as np
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
-# jax renamed check_rep -> check_vma; pass whichever this version accepts
-_ckw = ("check_vma" if "check_vma" in inspect.signature(shard_map).parameters
-        else "check_rep")
+import jax, jax.numpy as jnp, numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.distributed.compression import compress_grads, make_error_feedback_state
 
@@ -105,7 +101,7 @@ def body(g_shard, e_shard):
     return sync["w"], new_e["w"]
 
 f = shard_map(body, mesh=mesh, in_specs=(P("data"), P(None)),
-              out_specs=(P(None), P(None)), **{_ckw: False})
+              out_specs=(P(None), P(None)), check_vma=False)
 sync, new_e = f(g["w"].reshape(32), ef["w"])
 exact = np.asarray(g["w"]).reshape(8, 4).mean(0)
 got = np.asarray(sync)
@@ -175,17 +171,12 @@ print("OK")
 @pytest.mark.slow
 def test_gcn_shardmap_psum_matches_single_device():
     out = run_multidevice("""
-import dataclasses, inspect, jax, jax.numpy as jnp, numpy as np
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
-_ckw = ("check_vma" if "check_vma" in inspect.signature(shard_map).parameters
-        else "check_rep")
-from jax.sharding import PartitionSpec as P
+import dataclasses, jax, jax.numpy as jnp, numpy as np
+from jax import shard_map
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.models.gnn import gcn
 from repro.graphs import erdos_renyi
-mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 e = erdos_renyi(48, 200, seed=0); n = int(e.max())+1
 pad = (-e.shape[0]) % 8
 src = jnp.asarray(np.concatenate([e[:,0], -np.ones(pad)]).astype(np.int32))
@@ -197,8 +188,8 @@ feat = jax.random.normal(jax.random.PRNGKey(1), (n, 12))
 single = gcn.apply(p, cfg, feat, None, src, dst)
 f = shard_map(lambda p, x, s, d: gcn.apply(p, cfg_ps, x, None, s, d),
               mesh=mesh, in_specs=(P(), P(), P("data"), P("data")),
-              out_specs=P(), **{_ckw: False})
-with mesh:
+              out_specs=P(), check_vma=False)
+with jax.set_mesh(mesh):
     sharded = jax.jit(f)(p, feat, src, dst)
 np.testing.assert_allclose(np.asarray(single), np.asarray(sharded), rtol=2e-4, atol=2e-4)
 g = jax.grad(lambda p: jnp.sum(f(p, feat, src, dst)**2))(p)

@@ -213,7 +213,9 @@ def random_edges(rnd, n, m):
     return canonicalize_edges(np.stack([u, v], axis=1))
 
 
-@settings(max_examples=20)
+# no deadline: the first example of each shape pays a jit compile, and
+# derandomized draws keep the example set the same on every run
+@settings(max_examples=20, deadline=None, derandomize=True)
 @given(st.randoms(), st.integers(2, 40), st.integers(0, 120),
        st.integers(1, 8), st.sampled_from([None, 1, 64]))
 def check(rnd, n, m, k, budget):

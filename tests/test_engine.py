@@ -189,6 +189,29 @@ def test_uint64_accumulation_regression():
     assert accumulate_partials(parts) == 2 * (2**31 - 1) + 7
 
 
+def test_engine_fold_of_a_total_past_int32(family_graphs):
+    """The engine's own fold of device partials, end to end, on a total
+    past 2³¹: every chunk reports three near-int32-max partials."""
+    from repro.core.engine import WedgeBackend, register_backend, _BACKEND_FACTORIES
+
+    near_max = 2**31 - 1
+
+    class Saturating(WedgeBackend):
+        name = "saturating"
+
+        def count_chunk(self, adj, chunk):
+            return np.full((3,), near_max, np.int32)
+
+    register_backend("saturating", lambda **_: Saturating())
+    try:
+        tc = TriangleCounter(method="saturating", max_wedge_chunk=64)
+        total = tc.count(family_graphs["kron10"])
+        n = tc.last_stats.n_chunks
+        assert n > 1 and total == 3 * n * near_max > 2**32
+    finally:
+        del _BACKEND_FACTORIES["saturating"]
+
+
 def test_accumulation_matches_over_many_chunks(family_graphs, family_oracle):
     """Many tiny chunks exercise the host accumulation path end to end."""
     e = family_graphs["watts_strogatz"]

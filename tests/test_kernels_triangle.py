@@ -5,6 +5,7 @@ Interpret-mode parity for every member — scalar count, per-node
 widths, including all-padding tiles, empty buckets and explicit tile
 overrides (the autotuner's hook).
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -14,6 +15,7 @@ except ImportError:  # container image has no hypothesis; use the local stub
     from _hypothesis_stub import given, settings, st
 
 from repro.core import bucketize_edges, count_triangles, gather_panels, preprocess
+from repro.core.count import gather_elements, gather_windows
 from repro.kernels.triangle_count import (
     intersect_count_pallas,
     intersect_per_node_pallas,
@@ -89,11 +91,12 @@ def test_arm_closure_consistency(rng):
 
 
 def test_explicit_tile_override_parity(rng):
-    """tiles=(TB, TLv) overrides (the autotuner hook) never change results."""
+    """tiles=(TB, TLv) overrides (the autotuner hook) never change results,
+    and a tile the TPU compiler would refuse is refused here, not clamped."""
     a = random_panels(rng, 23, 64, np.int32)
     c = random_panels(rng, 23, 640, np.int32)
     ref_cnt, ref_arm, ref_clo = intersect_support_ref(a, c)
-    for tiles in [(1, 128), (8, 256), (64, 512), (256, 4096)]:
+    for tiles in [(128, 128), (128, 256), (256, 512), (256, 4096)]:
         cnt, arm, clo = intersect_support_pallas(a, c, tiles=tiles)
         np.testing.assert_array_equal(np.asarray(cnt), np.asarray(ref_cnt)), tiles
         np.testing.assert_array_equal(np.asarray(arm), np.asarray(ref_arm))
@@ -102,6 +105,9 @@ def test_explicit_tile_override_parity(rng):
             np.asarray(intersect_count_pallas(a, c, tiles=tiles)),
             np.asarray(ref_cnt),
         )
+    for bad in [(1, 128), (8, 256), (64, 512), (128, 200)]:
+        with pytest.raises(ValueError, match="tiles="):
+            intersect_count_pallas(a, c, tiles=bad)
 
 
 @settings(max_examples=20, deadline=None)
@@ -131,6 +137,51 @@ def test_degree_skew_bucketing(small_graphs):
         total_arm += int(np.asarray(arm).sum())
     assert total == count_triangles(e)
     assert total_arm == total  # each hit has exactly one arm slot
+
+
+def _slices(col, base, length, width):
+    out = np.full((len(base), width), -1, np.int32)
+    for i, (s, n) in enumerate(zip(base, length)):
+        out[i, :n] = col[s : s + n]
+    return out
+
+
+@pytest.mark.parametrize("width", [16, 64, 256, 1024, 4096, 16384])
+@pytest.mark.parametrize("form", [gather_elements, gather_windows],
+                         ids=["elements", "windows"])
+def test_panel_gather_matches_slices(form, width, rng):
+    """Both panel gathers return ``col[base : base + length]``, −1 padded:
+    every lane shift 0..127 of a 128-lane row at full and random lengths,
+    and every base within 130 slots of a window from ``col``'s end."""
+    m = 2 * width + 3 * 128 + 77  # not a multiple of 128
+    col = np.sort(rng.integers(0, 1 << 30, m)).astype(np.int32)
+    shifted = 128 + np.arange(128)
+    tail = np.arange(m - width - 130, m + 1)
+    base = np.concatenate([shifted, shifted, tail, [0]])
+    room = m - base
+    length = np.concatenate([
+        np.full(128, width), rng.integers(0, width + 1, 128),
+        np.full(tail.size, width), [0],
+    ])
+    length = np.minimum(length, room)
+    got = jax.jit(form, static_argnames="width")(
+        jnp.asarray(col), jnp.asarray(base, jnp.int32), jnp.asarray(length, jnp.int32),
+        width=width)
+    np.testing.assert_array_equal(np.asarray(got), _slices(col, base, length, width))
+
+
+def test_window_gather_matches_element_gather_on_graph(small_graphs):
+    """The TPU's panel gather and the CPU's agree on every bucket of a
+    skewed graph's real (base, length) pairs."""
+    e = small_graphs["kron"]
+    csr = preprocess(jnp.asarray(e), n_nodes=int(e.max()) + 1)
+    for width, idx in bucketize_edges(csr).items():
+        for ends in (csr.src, csr.col):
+            node = ends[jnp.asarray(idx)]
+            args = (csr.col, csr.row_offsets[node], csr.out_degree[node])
+            np.testing.assert_array_equal(
+                np.asarray(gather_windows(*args, width)),
+                np.asarray(gather_elements(*args, width)))
 
 
 def test_empty_rows():

@@ -22,12 +22,28 @@ from repro.core.tuning import (
 
 
 def test_candidate_grid_respects_vmem_budget():
-    for (n, lu, lv) in [(32, 16, 16), (512, 256, 1024), (8, 4096, 4096)]:
+    """Every candidate is a tile the TPU compiler accepts: TB a multiple of
+    the lane width, TLv lane-aligned or the whole width, and the blocks of
+    every family member inside the kernel's VMEM budget."""
+    from repro.kernels.triangle_count.triangle_count import (
+        KINDS,
+        LANES,
+        VMEM_BUDGET,
+        _vmem_bytes,
+        check_tiles,
+    )
+
+    for (n, lu, lv) in [(32, 16, 16), (512, 256, 1024), (8, 4096, 4096),
+                        (3000, 64, 640), (100, 16384, 16384)]:
         cands = candidate_tiles(n, lu, lv)
         assert cands, (n, lu, lv)
         for c in cands:
-            assert c.block_edges * lu * min(c.tlv, lv) <= (1 << 21), (c, n, lu, lv)
-            assert 1 <= c.block_edges <= max(n, 256)
+            assert c.block_edges % LANES == 0, (c, n, lu, lv)
+            assert c.block_edges <= max(-(-n // LANES) * LANES, LANES)
+            assert c.tlv % LANES == 0 or c.tlv >= lv, (c, n, lu, lv)
+            for kind in KINDS:
+                tb, tlu, tlv = check_tiles(c.tiles, n, lu, lv, kind)
+                assert _vmem_bytes(kind, tb, tlu, tlv) <= VMEM_BUDGET
 
 
 def test_shape_key_pow2_buckets():
@@ -96,8 +112,10 @@ def test_cache_survives_corrupt_file(tmp_path):
 
 
 def test_autotune_result_is_admissible():
+    from repro.kernels.triangle_count.triangle_count import check_tiles
+
     cfg = autotune_tiles(8, 16, 16, iters=1, warmup=0)
-    assert cfg.block_edges * 16 * min(cfg.tlv, 16) <= (1 << 21)
+    assert check_tiles(cfg.tiles, 8, 16, 16) == (128, 16, 16)
     assert cfg.us > 0.0
 
 
