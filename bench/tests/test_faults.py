@@ -1,0 +1,72 @@
+"""A whole run, with the chip check skipped and the timed path broken
+underneath, comes out not correct; unbroken, it comes out correct.
+
+The faults a one-chip answer can have: the answer altered where it is
+produced (the kernel's per-edge counts), half of every chunk left out,
+and the work skipped so that the answer's accumulator never moves.  No
+cell exchanges data between chips, so that fault has no test.
+"""
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from bench import run as harness
+from bench.tests.small import TEST_SPEC, WORKLOADS, pallas, small_config  # noqa: F401
+from repro.core.engine import PallasBackend, PanelChunk
+
+
+def altered(monkeypatch):
+    count, per_node = PallasBackend.intersect_count, PallasBackend.intersect_per_node
+    monkeypatch.setattr(PallasBackend, "intersect_count",
+                        lambda self, a, b: count(self, a, b) + 1)
+
+    def per_node_plus(self, a, b):
+        c, arm = per_node(self, a, b)
+        return c + 1, arm
+
+    monkeypatch.setattr(PallasBackend, "intersect_per_node", per_node_plus)
+
+
+def half_left_out(monkeypatch):
+    plan = PallasBackend.plan
+
+    def halve(chunk):
+        u, v = np.array(chunk.u), np.array(chunk.v)
+        u[len(u) // 2:] = -1
+        v[len(v) // 2:] = -1
+        return PanelChunk(chunk.edge_idx, u, v, chunk.width)
+
+    def half_plan(self, work, budget, **kw):
+        p = plan(self, work, budget, **kw)
+        return p._replace(chunks=iter([halve(c) for c in p.chunks]))
+
+    monkeypatch.setattr(PallasBackend, "plan", half_plan)
+
+
+def unchanged(monkeypatch):
+    monkeypatch.setattr(PallasBackend, "count_chunk",
+                        lambda self, adj, chunk: jnp.zeros((1,), jnp.int32))
+    monkeypatch.setattr(PallasBackend, "per_node_chunk",
+                        lambda self, adj, chunk, n_out: jnp.zeros((n_out,), jnp.int32))
+
+
+def run_small(workload):
+    return harness.run_cell(TEST_SPEC, workload, 2**31 + 9, 0.0, False,
+                            config=small_config(workload), require_tpu=False)
+
+
+@pytest.mark.parametrize("workload", WORKLOADS)
+def test_sound_run_is_correct(workload, pallas):  # noqa: F811
+    result = run_small(workload)
+    assert result["correct"] and result["failed"] == 0
+    assert list(result)[-1] == "compared"
+
+
+@pytest.mark.parametrize("fault", [altered, half_left_out, unchanged])
+@pytest.mark.parametrize("workload", WORKLOADS)
+def test_fault_is_caught(workload, fault, pallas, monkeypatch):  # noqa: F811
+    fault(monkeypatch)
+    result = run_small(workload)
+    assert not result["correct"]
+    assert result["failed"] == result["attempted"] > 0
+    assert any(c["value"] > c["limit"] for c in result["compared"].values())
