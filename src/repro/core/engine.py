@@ -102,9 +102,9 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 import math
 import os
-import time
 import warnings
 from typing import Iterator, NamedTuple
 
@@ -249,19 +249,16 @@ class EngineStats:
     ``None``: round-robin striping balances skewed degree
     distributions).
 
-    ``timings`` breaks the call's wall clock into phases (seconds):
-    ``preprocess`` / ``plan`` / ``execute`` / ``fold``.  Without an
-    active tracer the kernels stay async-dispatched, so device compute
-    bills to whichever phase first blocks on the result (``fold``);
-    under ``repro.obs`` tracing each chunk is synced as it completes and
-    ``execute`` is genuine device time.  The phases always sum to the
-    call's wall clock either way.
-
-    The ``measured_*`` fields exist only for traced distributed runs:
-    per-stripe span-measured seconds (``stripe_times``) beside the
-    load-inferred skew, with ``skew_note`` set (and a ``RuntimeWarning``
-    raised) when the two disagree about which stripe straggles — load is
-    a proxy, the measurement wins.
+    ``timings`` breaks the call's wall clock into host phases (seconds):
+    ``preprocess`` / ``host_copy`` / ``plan`` / ``dispatch`` / ``wait`` /
+    ``fold``.  Each is the duration of the ``repro.obs`` span of the same
+    phase (``engine.<phase>``), from the same two clock reads, so the
+    timings and the spans agree by construction.  ``dispatch`` is the
+    enqueue of every chunk's copies and kernels; ``wait`` is the host
+    blocked until the device's partials are ready, which includes
+    whatever device work the enqueue did not overlap; the device's own
+    time per operation comes from a profiler trace, where the spans
+    appear as ``tc.engine.<phase>``.
     """
 
     method: str                  # executed schedule, never "auto"
@@ -276,10 +273,6 @@ class EngineStats:
     stripe_skew: float | None = None    # max/mean stripe wedge load
     straggler_stripe: int | None = None  # stripe flagged by the MAD rule
     timings: dict | None = None          # phase → seconds (see above)
-    stripe_times: tuple[float, ...] | None = None  # measured s/stripe (traced)
-    measured_stripe_skew: float | None = None      # max/mean measured time
-    measured_straggler_stripe: int | None = None   # MAD rule on measured times
-    skew_note: str | None = None         # loud load-vs-measured disagreement
 
 
 # ---------------------------------------------------------------------------
@@ -549,10 +542,8 @@ class StripedChunk(NamedTuple):
 class WorkPlan(NamedTuple):
     """A backend's chunking decision for one workload.
 
-    ``timings`` and ``stripe_times`` are filled in by ``run_workload``
-    on the plan it returns (backends leave them at the defaults):
-    phase → seconds, and — traced distributed runs only — measured
-    per-stripe seconds from the span probe.
+    ``timings`` is filled in by ``run_workload`` on the plan it returns
+    (backends leave it at the default): phase → seconds.
     """
 
     chunks: Iterator
@@ -562,7 +553,6 @@ class WorkPlan(NamedTuple):
     n_stripes: int = 1                        # §III-E stripes (distributed)
     stripe_loads: tuple[int, ...] | None = None  # wedge slots per stripe
     timings: dict | None = None                  # filled by run_workload
-    stripe_times: tuple[float, ...] | None = None  # filled when traced
 
 
 # ---------------------------------------------------------------------------
@@ -733,29 +723,31 @@ class PanelBackend(KernelBackend):
 
         return WorkPlan(iter(chunks), len(chunks), peak, total_wedges)
 
-    def _gather(self, adj, chunk):
-        return gather_panels_arrays(
-            adj.row_offsets, adj.col, adj.out_degree,
-            jnp.asarray(chunk.u), jnp.asarray(chunk.v), chunk.width,
-        )
+    @staticmethod
+    def _upload(chunk, *fields):
+        """The chunk's host index arrays, each copied to the device once."""
+        return tuple(jnp.asarray(getattr(chunk, f)) for f in fields)
+
+    def _gather(self, adj, u, v, width):
+        return gather_panels_arrays(adj.row_offsets, adj.col, adj.out_degree, u, v, width)
 
     def count_chunk(self, adj, chunk):
-        a, b, _, _ = self._gather(adj, chunk)
+        u, v = self._upload(chunk, "u", "v")
+        a, b, _, _ = self._gather(adj, u, v, chunk.width)
         return self.intersect_count(a, b)
 
     def per_node_chunk(self, adj, chunk, n_out):
-        a, b, _, _ = self._gather(adj, chunk)
+        u, v = self._upload(chunk, "u", "v")
+        a, b, _, _ = self._gather(adj, u, v, chunk.width)
         count, arm = self.intersect_per_node(a, b)
-        return _panel_scatter_per_node(
-            jnp.asarray(chunk.u), jnp.asarray(chunk.v), a, count, arm, n_out=n_out
-        )
+        return _panel_scatter_per_node(u, v, a, count, arm, n_out=n_out)
 
     def support_chunk(self, adj, chunk, m_out):
-        a, b, _, _ = self._gather(adj, chunk)
+        edge_idx, u, v = self._upload(chunk, "edge_idx", "u", "v")
+        a, b, _, _ = self._gather(adj, u, v, chunk.width)
         count, arm, closure = self.intersect_support(a, b)
         return _panel_scatter_support(
-            jnp.asarray(chunk.edge_idx), jnp.asarray(chunk.u), jnp.asarray(chunk.v),
-            adj.row_offsets, count, arm, closure, m_out=m_out,
+            edge_idx, u, v, adj.row_offsets, count, arm, closure, m_out=m_out,
         )
 
 
@@ -1066,6 +1058,7 @@ def run_workload(
     budget: int | None = None,
     n_out: int | None = None,
     bucket_pow2: bool = False,
+    call: int | None = None,
 ):
     """Plan → launch → accumulate one workload through a backend.
 
@@ -1077,17 +1070,26 @@ def run_workload(
     launch stats (``n_chunks``, ``peak_buffer``, ``total_wedges``) plus
     the phase ``timings``.
 
-    Observability: phase wall clocks (plan/execute/fold) are always
-    recorded — they are two ``perf_counter`` reads per phase.  Under an
-    active :mod:`repro.obs` tracer each chunk launch additionally gets a
-    span that *syncs* the partial before closing (``execute`` then
-    measures device compute, not async dispatch), and §III-E striped
-    chunks get a per-stripe timing probe (measured straggler detection).
+    Each phase is a :mod:`repro.obs` span, and its ``timings`` entry is
+    that span's duration: ``engine.plan`` (args ``edges``, ``chunks``),
+    ``engine.dispatch`` (the launch loop, one ``engine.chunk`` per chunk;
+    args ``chunks``, ``h2d_bytes`` and, for panel chunks, ``slots``),
+    ``engine.wait`` (one ``jax.block_until_ready``) and ``engine.fold``
+    (the host copy and int64/uint64 sum of the partials; arg ``bytes``).
+    A count launches every chunk, waits once and folds once; per-node and
+    support fold each chunk's full-length partial before the next chunk
+    launches, so they run the three phases once per chunk.  ``call``, when
+    given, tags every span with the caller's answer number.
     """
-    trc = obs.active()
-    t0 = time.perf_counter()
-    plan = backend.plan(work, budget, bucket_pow2=bucket_pow2)
-    timings = {"plan": time.perf_counter() - t0, "execute": 0.0, "fold": 0.0}
+    if kind not in CAPABILITIES:
+        raise ValueError(f"unknown workload kind {kind!r}")
+    ids = {} if call is None else {"call": call}
+    timings = {"plan": 0.0, "dispatch": 0.0, "wait": 0.0, "fold": 0.0}
+    with obs.span("engine.plan", cat="engine", args=ids) as sp:
+        plan = backend.plan(work, budget, bucket_pow2=bucket_pow2)
+        sp.set(edges=int(work.src_host.shape[0]), chunks=plan.n_chunks)
+    timings["plan"] = sp.seconds
+    # a no-op for device-resident adjacency (every engine answer)
     adj = _DeviceAdj(
         jnp.asarray(work.row_offsets), jnp.asarray(work.col),
         jnp.asarray(work.out_degree), work.n_steps,
@@ -1097,110 +1099,85 @@ def run_workload(
     obs.counter("engine.wedges_planned").add(plan.total_wedges)
     obs.counter("engine.chunks_launched").add(plan.n_chunks)
     obs.gauge("engine.peak_wedge_buffer").set(plan.peak_buffer)
-    stripe_acc: list | None = None
 
-    def launch(fn, chunk, i, *extra):
-        """One chunk launch, span-wrapped (and synced) when tracing."""
-        nonlocal stripe_acc
-        if trc is None:
-            return fn(adj, chunk, *extra)
-        with trc.span(f"{kind}.chunk", cat="engine",
-                      args={"chunk": i,
-                            "buffer": int(getattr(chunk, "buffer", 0))}) as sp:
-            part = sp.sync(fn(adj, chunk, *extra))
-        if isinstance(chunk, StripedChunk):
-            times = _probe_stripe_times(trc, adj, chunk)
-            if stripe_acc is None:
-                stripe_acc = [0.0] * len(times)
-            for s, dt in enumerate(times):
-                stripe_acc[s] += dt
+    def launch(fn, chunk, tally, *extra):
+        """Enqueue one chunk's copies and kernels, and count them.
+
+        The partial's copy to the host is enqueued too, so that it runs
+        as soon as the chunk is done, under the later chunks' compute,
+        and the fold finds it on the host.
+        """
+        with obs.span("engine.chunk", cat="engine", args={**ids, **_chunk_shape(chunk)}):
+            part = fn(adj, chunk, *extra)
+            if isinstance(part, jax.Array):
+                part.copy_to_host_async()
+        tally["chunks"] += 1
+        tally["h2d_bytes"] += _upload_bytes(kind, chunk)
+        if isinstance(chunk, PanelChunk):
+            tally["slots"] = tally.get("slots", 0) + 2 * len(chunk.u) * chunk.width
         return part
 
     def done(value):
-        return value, plan._replace(
-            timings=timings,
-            stripe_times=tuple(stripe_acc) if stripe_acc else None,
-        )
+        return value, plan._replace(timings=timings)
 
     if kind == "count":
-        # collect device partials first, accumulate once: launches stay
-        # async-dispatched instead of syncing host-side per chunk (under
-        # tracing each launch IS synced — that is the point of the span)
-        t0 = time.perf_counter()
-        partials = [
-            launch(backend.count_chunk, chunk, i)
-            for i, chunk in enumerate(plan.chunks)
-        ]
-        if san is not None:
-            san.check_partials(partials, kind="count")
-        timings["execute"] = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        total = accumulate_partials(partials)
-        timings["fold"] = time.perf_counter() - t0
+        tally = {"chunks": 0, "h2d_bytes": 0}
+        with obs.span("engine.dispatch", cat="engine", args=ids) as sp:
+            partials = [launch(backend.count_chunk, chunk, tally) for chunk in plan.chunks]
+            sp.set(**tally)
+        timings["dispatch"] = sp.seconds
+        with obs.span("engine.wait", cat="engine", args=ids) as sp:
+            jax.block_until_ready(partials)
+        timings["wait"] = sp.seconds
+        with obs.span("engine.fold", cat="engine", args=ids) as sp:
+            if san is not None:
+                san.check_partials(partials, kind="count")
+            total = accumulate_partials(partials)
+            sp.set(bytes=sum(int(p.nbytes) for p in partials))
+        timings["fold"] = sp.seconds
         return done(total)
+
     if kind == "per_node":
-        if n_out is None:
-            n_out = adj.row_offsets.shape[0] - 1
-        out = np.zeros((n_out,), np.int64)
-        t_loop = time.perf_counter()
-        for i, chunk in enumerate(plan.chunks):
-            part = launch(backend.per_node_chunk, chunk, i, n_out)
+        fn = backend.per_node_chunk
+        m = adj.row_offsets.shape[0] - 1 if n_out is None else n_out
+    else:
+        fn, m = backend.support_chunk, int(work.src_host.shape[0])
+    out = np.zeros((m,), np.int64)
+    for i, chunk in enumerate(plan.chunks):
+        tally = {"chunks": 0, "h2d_bytes": 0}
+        with obs.span("engine.dispatch", cat="engine", args=ids) as sp:
+            part = launch(fn, chunk, tally, m)
+            sp.set(**tally)
+        timings["dispatch"] += sp.seconds
+        with obs.span("engine.wait", cat="engine", args=ids) as sp:
+            jax.block_until_ready(part)
+        timings["wait"] += sp.seconds
+        with obs.span("engine.fold", cat="engine", args=ids) as sp:
             if san is not None:
-                san.check_partial(part, kind="per_node", context=f"chunk {i}")
-            t0 = time.perf_counter()
+                san.check_partial(part, kind=kind, context=f"chunk {i}")
             out += np.asarray(part, dtype=np.int64)
-            timings["fold"] += time.perf_counter() - t0
-        timings["execute"] = time.perf_counter() - t_loop - timings["fold"]
-        return done(out)
-    if kind == "support":
-        m_out = int(work.src_host.shape[0])
-        out = np.zeros((m_out,), np.int64)
-        t_loop = time.perf_counter()
-        for i, chunk in enumerate(plan.chunks):
-            part = launch(backend.support_chunk, chunk, i, m_out)
-            if san is not None:
-                san.check_partial(part, kind="support", context=f"chunk {i}")
-            t0 = time.perf_counter()
-            out += np.asarray(part, dtype=np.int64)
-            timings["fold"] += time.perf_counter() - t0
-        timings["execute"] = time.perf_counter() - t_loop - timings["fold"]
-        return done(out)
-    raise ValueError(f"unknown workload kind {kind!r}")
+            sp.set(bytes=int(part.nbytes))
+        timings["fold"] += sp.seconds
+    return done(out)
 
 
-def _probe_stripe_times(trc, adj: _DeviceAdj, chunk: StripedChunk) -> "list[float]":
-    """Measured per-stripe seconds for one §III-E striped chunk.
+def _chunk_shape(chunk) -> dict:
+    """An ``engine.chunk`` span's args: a panel chunk's ``width`` and
+    ``rows`` (padded rows included), else the launch's wedge ``buffer``."""
+    if isinstance(chunk, PanelChunk):
+        return {"width": chunk.width, "rows": len(chunk.u)}
+    return {"buffer": int(getattr(chunk, "buffer", 0))}
 
-    The striped collective executes all stripes in one fused dispatch, so
-    individual stripes are not separately observable from the host.  Under
-    tracing we therefore *re-run* the wedge-count kernel over each
-    stripe's −1-padded edge slice on the default device, synced, and
-    report those wall times — measured per-stripe cost beside the
-    load-inferred skew (Arifuzzaman et al. make load-vs-timing skew a
-    first-order concern; load is only a proxy).  One warm-up launch keeps
-    the (buffer, steps) compile out of the timed region.  Costs roughly
-    one extra pass over the chunk, paid only while a tracer is active.
-    """
-    src = np.asarray(chunk.src)
-    dst = np.asarray(chunk.dst)
-    warm = chunk_count_kernel(
-        jnp.asarray(src[0]), jnp.asarray(dst[0]),
-        adj.row_offsets, adj.col, adj.out_degree,
-        wedge_budget=chunk.buffer, n_steps=adj.n_steps,
-    )
-    jax.block_until_ready(warm)
-    times = []
-    for s in range(src.shape[0]):
-        t0 = time.perf_counter()
-        with trc.span("stripe.probe", cat="engine.stripes",
-                      args={"stripe": s}) as sp:
-            sp.sync(chunk_count_kernel(
-                jnp.asarray(src[s]), jnp.asarray(dst[s]),
-                adj.row_offsets, adj.col, adj.out_degree,
-                wedge_budget=chunk.buffer, n_steps=adj.n_steps,
-            ))
-        times.append(time.perf_counter() - t0)
-    return times
+
+def _upload_bytes(kind: str, chunk) -> int:
+    """Bytes of the chunk's host index arrays its launch copies to the
+    device: ``u``/``v`` of a panel chunk (and ``edge_idx`` for support),
+    ``src``/``dst`` of a wedge or striped chunk held on the host."""
+    if isinstance(chunk, PanelChunk):
+        arrays = (chunk.u, chunk.v) + ((chunk.edge_idx,) if kind == "support" else ())
+    else:
+        arrays = (getattr(chunk, "src", None), getattr(chunk, "dst", None))
+    return sum(a.nbytes for a in arrays if isinstance(a, np.ndarray))
 
 
 def iter_wedge_chunks(csr: OrientedCSR, max_wedge_chunk: int | None, *, bucket_pow2: bool = False):
@@ -1286,6 +1263,15 @@ def resolve_method(method: str, out_degree, *, mesh=None, widths=DEFAULT_WIDTHS)
 # ---------------------------------------------------------------------------
 
 
+_ANSWERS = itertools.count()
+
+
+def _answer_id() -> dict:
+    """``{"call": n}``: the process's answer number, which tags every
+    span of one engine answer."""
+    return {"call": next(_ANSWERS)}
+
+
 class TriangleCounter:
     """Unified, memory-bounded triangle counting over every schedule.
 
@@ -1352,11 +1338,11 @@ class TriangleCounter:
         oriented by a host-side filter, never re-canonicalized).
         """
         self.last_stats = None
-        with obs.span("engine.count", cat="engine"):
-            csr, prep_s = self._prepare_timed(edges, n_nodes)
+        with obs.span("engine.count", cat="engine", args=_answer_id()) as sp:
+            csr, prep_s = self._prepare_timed(edges, n_nodes, sp.args)
             if csr is None:
                 return 0
-            return self._run(csr, "count", self._resolve(csr), prep_s)
+            return self._run(csr, "count", prep_s, sp.args)
 
     def per_node(self, edges, n_nodes: int | None = None) -> np.ndarray:
         """Per-vertex triangle incidences, int64 host array.
@@ -1369,12 +1355,12 @@ class TriangleCounter:
         executes on every mesh device.
         """
         self.last_stats = None
-        with obs.span("engine.per_node", cat="engine"):
-            csr, prep_s = self._prepare_timed(edges, n_nodes)
+        with obs.span("engine.per_node", cat="engine", args=_answer_id()) as sp:
+            csr, prep_s = self._prepare_timed(edges, n_nodes, sp.args)
             if csr is None:
                 n = n_nodes if n_nodes is not None else getattr(edges, "n_nodes", 0) or 0
                 return np.zeros((n,), np.int64)
-            return self._run(csr, "per_node", self._resolve(csr), prep_s)
+            return self._run(csr, "per_node", prep_s, sp.args)
 
     def edge_support(self, edges, n_nodes: int | None = None) -> np.ndarray:
         """Per-directed-edge triangle support, int64 host array.
@@ -1385,11 +1371,11 @@ class TriangleCounter:
         which routes through this method.
         """
         self.last_stats = None
-        with obs.span("engine.support", cat="engine"):
-            csr, prep_s = self._prepare_timed(edges, n_nodes)
+        with obs.span("engine.support", cat="engine", args=_answer_id()) as sp:
+            csr, prep_s = self._prepare_timed(edges, n_nodes, sp.args)
             if csr is None:
                 return np.zeros((0,), np.int64)
-            return self._run(csr, "support", self._resolve(csr), prep_s)
+            return self._run(csr, "support", prep_s, sp.args)
 
     def per_node_counts(self, edges, n_nodes: int | None = None) -> np.ndarray:
         """Alias of :meth:`per_node` (clearer name for analytics callers)."""
@@ -1422,12 +1408,11 @@ class TriangleCounter:
 
     # -- shared plumbing ----------------------------------------------------
 
-    def _prepare_timed(self, edges, n_nodes: int | None):
+    def _prepare_timed(self, edges, n_nodes: int | None, ids: dict):
         """``(_prepare result, preprocess seconds)`` under a span."""
-        t0 = time.perf_counter()
-        with obs.span("engine.preprocess", cat="engine"):
+        with obs.span("engine.preprocess", cat="engine", args=ids) as sp:
             csr = self._prepare(edges, n_nodes)
-        return csr, time.perf_counter() - t0
+        return csr, sp.seconds
 
     def _prepare(self, edges, n_nodes: int | None) -> OrientedCSR | None:
         csr = prepare_oriented(edges, n_nodes)
@@ -1454,33 +1439,14 @@ class TriangleCounter:
 
     def _record(self, method, n_chunks, peak, total_wedges, m_dir,
                 resolved=None, fallback_reason=None, stripe_loads=None,
-                n_stripes=1, timings=None, stripe_times=None):
+                n_stripes=1, timings=None):
         skew = straggler = None
-        measured_skew = measured_straggler = None
-        note = None
-        load_rep = None
         if stripe_loads is not None:
             from repro.distributed.straggler import stripe_skew_report
 
             load_rep = stripe_skew_report(stripe_loads)
             skew = load_rep.skew
             straggler = load_rep.straggler_stripe
-        if stripe_times:
-            from repro.distributed.straggler import (
-                skew_disagreement_note,
-                stripe_skew_report,
-            )
-
-            # the MAD rule works on integer loads; nanoseconds keep the
-            # measured resolution through the int coercion
-            time_rep = stripe_skew_report([int(t * 1e9) for t in stripe_times])
-            measured_skew = time_rep.skew
-            measured_straggler = time_rep.straggler_stripe
-            if load_rep is not None:
-                note = skew_disagreement_note(load_rep, time_rep)
-                if note is not None:
-                    obs.counter("engine.skew_disagreements").add()
-                    warnings.warn(note, RuntimeWarning, stacklevel=3)
         self.last_stats = EngineStats(
             method=method,
             resolved_method=resolved or method,
@@ -1494,30 +1460,39 @@ class TriangleCounter:
             stripe_skew=skew,
             straggler_stripe=straggler,
             timings=timings,
-            stripe_times=tuple(stripe_times) if stripe_times else None,
-            measured_stripe_skew=measured_skew,
-            measured_straggler_stripe=measured_straggler,
-            skew_note=note,
         )
 
-    def _run(self, csr: OrientedCSR, kind: str, resolved: str,
-             prep_s: float = 0.0):
-        """Dispatch one workload through the capability-resolved backend."""
+    def _run(self, csr: OrientedCSR, kind: str, prep_s: float, ids: dict):
+        """Dispatch one workload through the capability-resolved backend.
+
+        ``engine.host_copy`` covers every read of the resident graph to
+        the host before planning (``auto`` resolution, the search depth,
+        the workload's host views); its ``bytes`` are those of the device
+        arrays read.  JAX keeps an array's host copy once made, so only
+        the first answer on a resident graph actually copies them.
+        """
+        with obs.span("engine.host_copy", cat="engine", args=ids) as sp:
+            resolved = self._resolve(csr)
+            work = workload_from_csr(csr)
+            sp.set(bytes=sum(
+                int(a.nbytes) for a in (csr.src, csr.col, csr.out_degree)
+                if isinstance(a, jax.Array)
+            ))
+        host_copy_s = sp.seconds
         backend, executed, reason = resolve_backend(
             resolved, kind, widths=self.widths, tuner=self.tuner,
             mesh=self.mesh, shorter_side=self.shorter_side,
         )
-        work = workload_from_csr(csr)
         value, plan = run_workload(
             backend, kind, work,
             budget=self.max_wedge_chunk,
             n_out=csr.n_nodes if kind == "per_node" else None,
+            call=ids["call"],
         )
         self._record(
             executed, plan.n_chunks, plan.peak_buffer, plan.total_wedges,
             csr.n_directed_edges, resolved=resolved, fallback_reason=reason,
             stripe_loads=plan.stripe_loads, n_stripes=plan.n_stripes,
-            timings={"preprocess": prep_s, **(plan.timings or {})},
-            stripe_times=plan.stripe_times,
+            timings={"preprocess": prep_s, "host_copy": host_copy_s, **plan.timings},
         )
         return value

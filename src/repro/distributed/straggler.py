@@ -13,7 +13,7 @@ synchronous collectives, a stripe with an outsized wedge load *is* the
 straggler — wall time per launch is the max over stripes — so load skew
 measured host-side from the plan equals the timing skew a profiler would
 see.  The report surfaces in ``EngineStats`` after every distributed
-call.  Both pieces are host-side and fully unit-testable without
+call; each chip's measured time is on its own plane of a profiler trace.  Both pieces are host-side and fully unit-testable without
 hardware.
 """
 from __future__ import annotations
@@ -27,7 +27,6 @@ from typing import Callable, Sequence
 __all__ = [
     "StragglerMonitor",
     "StripeSkewReport",
-    "skew_disagreement_note",
     "stripe_skew_report",
 ]
 
@@ -120,26 +119,3 @@ def stripe_skew_report(
             straggler = loads.index(mx)
     return StripeSkewReport(n, loads, mean, mx, skew, straggler)
 
-
-def skew_disagreement_note(
-    load_report: StripeSkewReport, measured_report: StripeSkewReport
-) -> "str | None":
-    """Loud note when load-inferred and measured stragglers disagree.
-
-    The engine's ``stripe_skew`` assumes wedge load is a faithful proxy
-    for stripe time ("the collectives are synchronous, so load skew *is*
-    timing skew").  Under tracing the per-stripe probe measures actual
-    times, and this is the tripwire for the proxy breaking — e.g. one
-    stripe's edges hitting a pathological search depth, or a device-side
-    imbalance invisible to the planner.  Returns ``None`` when both
-    reports agree (including both finding no straggler).
-    """
-    if load_report.straggler_stripe == measured_report.straggler_stripe:
-        return None
-    return (
-        "stripe skew disagreement: wedge-load inference flags stripe "
-        f"{load_report.straggler_stripe} (skew {load_report.skew:.2f}) but "
-        f"measured stripe times flag stripe {measured_report.straggler_stripe} "
-        f"(skew {measured_report.skew:.2f}); load is a proxy — trust the "
-        "measured times"
-    )

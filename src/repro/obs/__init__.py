@@ -3,9 +3,11 @@
 The observability layer the timing claims rest on (§V of the paper is
 *all* timings).  Three pieces:
 
-* :mod:`repro.obs.tracer` — hierarchical spans with explicit
-  ``block_until_ready`` sync points (device time, not async dispatch),
-  near-zero cost when disabled.
+* :mod:`repro.obs.tracer` — hierarchical spans of host phases.  Each
+  span is a ``jax.profiler.TraceAnnotation`` named ``tc.<name>`` (so a
+  profiler trace shows it on the clock of the device operations, and
+  device time comes from that trace), and an event of the active
+  :class:`Tracer` when one is on.  Spans never block on the device.
 * :mod:`repro.obs.counters` — process-wide counters/gauges (chunks
   launched, wedges planned, cache hits, capability fallbacks).
 * :mod:`repro.obs.export` — Chrome trace-event JSON (Perfetto-viewable)
@@ -18,13 +20,15 @@ Typical CLI wiring::
             graph = ...
         tc.count(graph)          # engine emits nested spans itself
 
-and in engine code wrapping device work::
+and in engine code, a host phase whose duration also feeds a timing::
 
-    with obs.span("count.chunk", cat="engine") as sp:
-        part = sp.sync(backend.count_chunk(adj, chunk))
+    with obs.span("engine.plan", cat="engine") as sp:
+        plan = backend.plan(work, budget)
+        sp.set(chunks=plan.n_chunks)
+    timings["plan"] = sp.seconds
 
 Importing this package never imports jax (the stdlib-only CI jobs use
-the validators); ``Span.sync`` imports it lazily.
+the validators); the profiler sink is used once jax has been imported.
 """
 from .counters import (
     Counter,
@@ -48,7 +52,7 @@ from .export import (
 )
 from .hist import N_BUCKETS, ConcurrentHistogram, Pow2Histogram, RollingHistogram
 from .tracer import (
-    NOOP_SPAN,
+    PROFILER_PREFIX,
     Span,
     Tracer,
     active,
@@ -56,7 +60,6 @@ from .tracer import (
     span,
     start_tracing,
     stop_tracing,
-    sync,
     tracing,
 )
 
@@ -65,7 +68,7 @@ __all__ = [
     "Gauge",
     "MetricsRegistry",
     "N_BUCKETS",
-    "NOOP_SPAN",
+    "PROFILER_PREFIX",
     "Pow2Histogram",
     "ConcurrentHistogram",
     "RollingHistogram",
@@ -83,7 +86,6 @@ __all__ = [
     "span",
     "start_tracing",
     "stop_tracing",
-    "sync",
     "to_chrome_trace",
     "to_jsonl_records",
     "trace_to_file",

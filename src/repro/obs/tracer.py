@@ -2,31 +2,35 @@
 
 The paper's claims are *timings* (§V: 8–15× over CPU, 3.8B triangles in
 under 10 s), so the repo needs a way to attribute a run's wall clock to
-its phases.  This module is the core of that layer: a context-manager
-span API producing nested, exportable timing events.
+its phases.  This module is the core of that layer: one context-manager
+span API with two sinks.
 
-Three design constraints shape everything here:
+* **The profiler's timeline.**  Every span enters a
+  ``jax.profiler.TraceAnnotation`` named ``tc.<span name>`` whose stats
+  are the span's integer and float args, whether or not a
+  :class:`Tracer` is active.  Under ``jax.profiler.trace`` the spans so
+  land in the device trace on the same clock as the device operations,
+  and an idle stretch of the device can be put down to the host phase
+  around it.  Outside a profiler trace an annotation costs about a
+  microsecond.
+* **The tracer's event list.**  Under an active :class:`Tracer` the same
+  span is also recorded as a plain dict (``name``/``cat``/``ts_ns``/
+  ``dur_ns``/``depth``/``args``) relative to the tracer's origin, ready
+  for the Chrome trace-event / JSONL exporters in :mod:`repro.obs.export`.
 
-* **Near-zero cost when disabled.**  Tracing is off by default; the hot
-  path (``obs.span(...)`` in ``run_workload``'s chunk loop) must then
-  cost one module-global read and allocate nothing.  ``span()`` returns
-  the shared :data:`NOOP_SPAN` singleton when no tracer is active — the
-  disabled path never constructs an object.
-* **Spans measure device time, not async dispatch.**  JAX dispatches
-  kernels asynchronously: wrapping a ``backend.count_chunk`` call in a
-  naive timer measures enqueue latency while the actual compute lands in
-  whichever later operation blocks (usually the host fold).  A span
-  wrapping device work must therefore call :meth:`Span.sync` (which is
-  ``jax.block_until_ready`` under an active tracer and the identity
-  otherwise) before it closes.  The trilint ``obs_discipline`` pass
-  enforces this statically.
-* **Import-time stdlib-only.**  ``jax`` is imported lazily inside
-  ``sync`` so the exporters and validators run in jax-free contexts
-  (the stdlib-only CI lint job validates trace schemas without jax).
+A span times the host: JAX dispatches kernels asynchronously, so a span
+around a launch measures the enqueue, and device time comes from the
+device trace.  Spans never block on the device — a traced run does the
+same work as an untraced one.  The trilint ``obs_discipline`` pass
+holds spans over device work to this: such a span is named as a
+dispatch (``.dispatch``/``.chunk``) or blocks on its result itself.
+A span's own clock reads are exposed (:attr:`Span.seconds`), so
+callers derive phase timings from the very reads that bound the span.
 
-Events are recorded as plain dicts (``name``/``cat``/``ts_ns``/
-``dur_ns``/``depth``/``args``) relative to the tracer's origin, ready
-for the Chrome trace-event / JSONL exporters in :mod:`repro.obs.export`.
+``jax`` is never imported here: the profiler sink is used once the
+process has imported jax, since no profiler trace can run before that.
+Exporters and validators so stay usable in jax-free contexts.
+
 A tracer also runs a :class:`repro.check.runtime.CompileAuditor` for its
 lifetime, so every exported trace reports how many jit traces the run
 minted per kernel.
@@ -34,10 +38,12 @@ minted per kernel.
 from __future__ import annotations
 
 import contextlib
+import numbers
+import sys
 import time
 
 __all__ = [
-    "NOOP_SPAN",
+    "PROFILER_PREFIX",
     "Span",
     "Tracer",
     "active",
@@ -45,47 +51,89 @@ __all__ = [
     "span",
     "start_tracing",
     "stop_tracing",
-    "sync",
     "tracing",
 ]
 
+PROFILER_PREFIX = "tc."  # a span's name on the profiler's timeline
+
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, once jax is imported
+
+
+def _annotation_class():
+    global _ANNOTATION
+    if _ANNOTATION is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
+
+def _stats(args: dict) -> dict:
+    """The integer and float args, as profiler stats."""
+    out = {}
+    for k, v in args.items():
+        t = type(v)
+        if t is int or t is float:
+            out[k] = v
+        elif t is bool:
+            continue
+        elif isinstance(v, numbers.Integral):
+            out[k] = int(v)
+        elif isinstance(v, numbers.Real):
+            out[k] = float(v)
+    return out
+
 
 class Span:
-    """One live span of an active :class:`Tracer` (context manager).
+    """One span (context manager): a profiler annotation, and an event of
+    the active :class:`Tracer` if there is one.
 
-    Records an event on ``__exit__`` even when the body raises (the
+    Records its event on ``__exit__`` even when the body raises (the
     event then carries an ``error`` key) — a crash mid-phase still
-    leaves a closed, exportable span.  Call :meth:`sync` on any value
-    backed by device computation before the span closes, so the span
-    measures compute rather than async dispatch.
+    leaves a closed, exportable span.  After the span closes,
+    :attr:`seconds` is its duration from the same two clock reads that
+    bound its event.
     """
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_depth")
+    __slots__ = ("_tracer", "name", "cat", "args", "t0_ns", "t1_ns", "_depth", "_ann")
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str, args):
+    def __init__(self, tracer: "Tracer | None", name: str, cat: str, args):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = dict(args) if args else None
-        self._t0 = 0
+        self.t0_ns = self.t1_ns = 0
         self._depth = 0
+        self._ann = None
 
     def __enter__(self) -> "Span":
+        cls = _annotation_class()
+        if cls is not None:
+            self._ann = cls(PROFILER_PREFIX + self.name)
+            self._ann.__enter__()
         t = self._tracer
-        self._depth = t._depth
-        t._depth += 1
-        self._t0 = time.perf_counter_ns()
+        if t is not None:
+            self._depth = t._depth
+            t._depth += 1
+        self.t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        t1 = time.perf_counter_ns()
+        ann = self._ann
+        if ann is not None and self.args:
+            ann.set_metadata(**_stats(self.args))
+        self.t1_ns = time.perf_counter_ns()
+        if ann is not None:
+            ann.__exit__(exc_type, exc, tb)
         t = self._tracer
+        if t is None:
+            return False
         t._depth = self._depth
         event = {
             "name": self.name,
             "cat": self.cat,
-            "ts_ns": self._t0 - t._origin_ns,
-            "dur_ns": t1 - self._t0,
+            "ts_ns": self.t0_ns - t._origin_ns,
+            "dur_ns": self.t1_ns - self.t0_ns,
             "depth": self._depth,
         }
         if self.args:
@@ -95,48 +143,18 @@ class Span:
         t.events.append(event)
         return False
 
-    def sync(self, value):
-        """``jax.block_until_ready(value)`` — the span's sync point.
-
-        Ensures the span's close time covers the device work that
-        produced ``value`` instead of just its dispatch.
-        """
-        import jax
-
-        return jax.block_until_ready(value)
+    @property
+    def seconds(self) -> float:
+        """Seconds between the span's opening and closing clock reads."""
+        return (self.t1_ns - self.t0_ns) / 1e9
 
     def set(self, **kwargs) -> "Span":
-        """Attach/overwrite args on the span (shows up in exports)."""
+        """Attach/overwrite args (exported, and the numeric ones become
+        profiler stats when the span closes)."""
         if self.args is None:
             self.args = {}
         self.args.update(kwargs)
         return self
-
-
-class _NoopSpan:
-    """The disabled-mode span: every operation is free and allocation-less.
-
-    A single module-level instance (:data:`NOOP_SPAN`) is shared by all
-    disabled ``span()`` calls — tests assert the identity to pin the
-    no-allocation guarantee.
-    """
-
-    __slots__ = ()
-
-    def __enter__(self) -> "_NoopSpan":
-        return self
-
-    def __exit__(self, exc_type, exc, tb) -> bool:
-        return False
-
-    def sync(self, value):
-        return value
-
-    def set(self, **kwargs) -> "_NoopSpan":
-        return self
-
-
-NOOP_SPAN = _NoopSpan()
 
 
 class Tracer:
@@ -202,8 +220,8 @@ class Tracer:
 # -- module-level switchboard ------------------------------------------------
 #
 # One active tracer per process, mirroring how the engine's stats and
-# fallback warnings are process-global.  The disabled fast path is a
-# single global read.
+# fallback warnings are process-global.  With no tracer a span is only
+# its profiler annotation and its two clock reads.
 
 _ACTIVE: Tracer | None = None
 
@@ -217,21 +235,10 @@ def enabled() -> bool:
     return _ACTIVE is not None
 
 
-def span(name: str, cat: str = "", args=None):
-    """A span on the active tracer, or :data:`NOOP_SPAN` when disabled."""
-    t = _ACTIVE
-    if t is None:
-        return NOOP_SPAN
-    return t.span(name, cat, args)
-
-
-def sync(value):
-    """Block on ``value`` iff tracing is active (free otherwise)."""
-    if _ACTIVE is None:
-        return value
-    import jax
-
-    return jax.block_until_ready(value)
+def span(name: str, cat: str = "", args=None) -> Span:
+    """A span: on the profiler's timeline always, on the active tracer
+    when there is one."""
+    return Span(_ACTIVE, name, cat, args)
 
 
 def start_tracing(tracer: Tracer | None = None) -> Tracer:

@@ -79,6 +79,34 @@ def test_obs_discipline_synced_and_host_spans_not_flagged():
     assert "chunk_count_kernel" in in_fixture[0].message
 
 
+def test_obs_discipline_dispatch_spans_and_blocks(tmp_path):
+    """D1 lets a span over a launch through when its name says it times
+    the enqueue (``.dispatch``/``.chunk``, f-strings included) or when it
+    blocks; any other name over a launch is flagged."""
+    core = tmp_path / "core"
+    core.mkdir()
+    (core / "mod.py").write_text(
+        "import jax\n"
+        "def a(obs, k, c):\n"
+        "    with obs.span(f'{k}.chunk'):\n"
+        "        return c.count_chunk(1)\n"
+        "def b(obs, c):\n"
+        "    with obs.span('engine.dispatch') as sp:\n"
+        "        return [c.count_chunk(i) for i in range(2)]\n"
+        "def d(obs, c):\n"
+        "    with obs.span('engine.wait'):\n"
+        "        return jax.block_until_ready(c.count_chunk(1))\n"
+        "def e(obs, c):\n"
+        "    with obs.span('engine.dispatched_count'):\n"
+        "        return c.count_chunk(1)\n"
+        "def f(obs, c):\n"
+        "    with obs.span('engine.kernel') as sp:\n"
+        "        return sp.sync(c.count_chunk(1))\n"
+    )
+    flagged = run_checks(tmp_path, select=["obs_discipline"])
+    assert sorted(f.line for f in flagged) == [12, 15], [f.render() for f in flagged]
+
+
 def test_stats_lifecycle_compliant_method_not_flagged():
     findings = run_checks(FIXTURES, select=["stats_lifecycle"])
     flagged = {f.message.split("`")[1] for f in findings}

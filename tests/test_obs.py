@@ -7,7 +7,6 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.obs.tracer import _NoopSpan
 
 
 @pytest.fixture(autouse=True)
@@ -62,22 +61,19 @@ def test_span_set_attaches_args():
 
 
 def test_disabled_span_is_the_shared_noop_singleton():
-    # the near-zero-overhead contract: disabled span() allocates nothing —
-    # every call returns the same module-level singleton
+    # with no tracer a span records no event anywhere, never blocks on the
+    # device (it has no sync), and still times its body from its own reads
     assert not obs.enabled()
-    s1 = obs.span("anything", cat="x", args={"k": 1})
-    s2 = obs.span("else")
-    assert s1 is s2 is obs.NOOP_SPAN
-    assert isinstance(s1, _NoopSpan)
-    with s1 as sp:
-        obj = object()
-        assert sp.sync(obj) is obj  # identity, no jax import
-        assert sp.set(x=1) is sp
-
-
-def test_disabled_sync_is_identity():
-    obj = object()
-    assert obs.sync(obj) is obj
+    sp = obs.span("anything", cat="x", args={"k": 1})
+    assert isinstance(sp, obs.Span) and not hasattr(sp, "sync")
+    with sp as inner:
+        assert inner is sp and inner.set(x=1) is sp
+    assert sp.args == {"k": 1, "x": 1}
+    assert sp.t1_ns >= sp.t0_ns > 0
+    assert sp.seconds == (sp.t1_ns - sp.t0_ns) / 1e9
+    with obs.tracing() as t:
+        pass
+    assert t.events == []
 
 
 def test_nested_start_tracing_raises():
@@ -249,26 +245,10 @@ def test_env_fingerprint_has_stdlib_and_jax_fields():
 
 
 # ---------------------------------------------------------------------------
-# measured stripe skew satellite: disagreement note
-
-
-def test_skew_disagreement_note():
-    from repro.distributed.straggler import (
-        skew_disagreement_note,
-        stripe_skew_report,
-    )
-
-    load = stripe_skew_report([100, 100, 100, 400])
-    agree = stripe_skew_report([10, 10, 10, 40])
-    disagree = stripe_skew_report([400, 100, 100, 100])
-    assert skew_disagreement_note(load, agree) is None
-    note = skew_disagreement_note(load, disagree)
-    assert note is not None and "disagreement" in note
-    assert "stripe 3" in note and "stripe 0" in note
-
-
-# ---------------------------------------------------------------------------
 # engine + analytics integration
+
+
+PHASES = ("preprocess", "host_copy", "plan", "dispatch", "wait", "fold")
 
 
 def test_engine_count_emits_spans_and_timings(small_graphs):
@@ -284,18 +264,23 @@ def test_engine_count_emits_spans_and_timings(small_graphs):
 
     names = [e["name"] for e in t.events]
     assert "engine.count" in names
-    assert "engine.preprocess" in names
-    assert "count.chunk" in names
+    for phase in PHASES:
+        assert names.count(f"engine.{phase}") == 1, (phase, names)
+    assert "engine.chunk" in names
 
     es = tc.last_stats
     assert es.timings is not None
-    assert set(es.timings) == {"preprocess", "plan", "execute", "fold"}
-    assert all(v >= 0 for v in es.timings.values())
-    # the phase breakdown must reconcile with the span-measured wall
+    assert tuple(es.timings) == PHASES
+    # each timing is its span's duration, from the same clock reads
+    for phase in PHASES:
+        (ev,) = [e for e in t.events if e["name"] == f"engine.{phase}"]
+        assert es.timings[phase] == ev["dur_ns"] / 1e9
+    # the phases cover the answer's span
     span_wall = next(e for e in t.events if e["name"] == "engine.count")
     wall_s = span_wall["dur_ns"] / 1e9
     total = sum(es.timings.values())
-    assert abs(total - wall_s) <= max(0.1 * wall_s, 0.005), (total, wall_s)
+    assert total <= wall_s
+    assert total >= 0.95 * wall_s - 0.001, (total, wall_s)
 
 
 def test_untraced_count_still_fills_timings(small_graphs):
@@ -343,3 +328,153 @@ def test_incremental_probe_spans(small_graphs):
     names = [e["name"] for e in t.events]
     for n in ("probe.without", "probe.with", "probe.delta"):
         assert n in names, names
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's timeline
+
+
+def _profiled(path, fn):
+    """Run ``fn`` under ``jax.profiler.trace``; its ``tc.`` events in order."""
+    import glob
+
+    import jax
+
+    with jax.profiler.trace(str(path)):
+        out = fn()
+    (pb,) = glob.glob(str(path / "**" / "*.xplane.pb"), recursive=True)
+    data = jax.profiler.ProfileData.from_file(pb)
+    events = sorted(
+        (ev.start_ns, -ev.duration_ns, ev.name, ev.duration_ns, dict(ev.stats))
+        for plane in data.planes for line in plane.lines for ev in line.events
+        if ev.name.startswith(obs.PROFILER_PREFIX)
+    )
+    return out, [(n, s, s + d, st) for s, _, n, d, st in events]
+
+
+def _panel_csr():
+    from repro.core import prepare_oriented
+    from repro.graphs import kronecker_rmat
+
+    return prepare_oriented(kronecker_rmat(8, edge_factor=8, seed=2))
+
+
+def test_profiler_trace_holds_nested_engine_spans(tmp_path):
+    from repro.core import TriangleCounter
+
+    csr = _panel_csr()
+    tc = TriangleCounter(method="panel", max_wedge_chunk=1 << 10)
+    want = tc.count(csr)                       # warm, untraced
+    got, events = _profiled(tmp_path, lambda: tc.count(csr))
+    assert got == want
+    (answer,) = [e for e in events if e[0] == "tc.engine.count"]
+    call = answer[3]["call"]
+    assert isinstance(call, int)
+    kids = [e for e in events if e is not answer]
+    assert all(answer[1] <= s and e <= answer[2] and st["call"] == call
+               for _, s, e, st in kids)
+    by = {name: [e for e in kids if e[0] == name] for name, *_ in kids}
+    for phase in PHASES:
+        assert len(by[f"tc.engine.{phase}"]) == 1, phase
+    (plan,) = by["tc.engine.plan"]
+    (dispatch,) = by["tc.engine.dispatch"]
+    assert plan[3]["edges"] == csr.n_directed_edges
+    assert plan[3]["chunks"] == dispatch[3]["chunks"] == len(by["tc.engine.chunk"]) > 1
+    for _, s, e, st in by["tc.engine.chunk"]:
+        assert dispatch[1] <= s and e <= dispatch[2]
+        assert st["width"] in (16, 64) and st["rows"] > 0
+    assert dispatch[3]["slots"] > 0 and dispatch[3]["h2d_bytes"] > 0
+    assert by["tc.engine.host_copy"][0][3]["bytes"] > 0
+    assert by["tc.engine.fold"][0][3]["bytes"] > 0
+    # the phases follow one another in answer order
+    starts = [by[f"tc.engine.{p}"][0][1] for p in PHASES]
+    assert starts == sorted(starts)
+
+
+def test_profiler_spans_match_tracer_events(tmp_path):
+    from repro.core import TriangleCounter
+
+    csr = _panel_csr()
+    tc = TriangleCounter(method="panel", max_wedge_chunk=1 << 10)
+    tc.count(csr)
+
+    def traced():
+        with obs.tracing() as t:
+            tc.count(csr)
+        return t
+
+    t, events = _profiled(tmp_path, traced)
+    assert len(t.events) == len(events) > 8
+    for name in {e["name"] for e in t.events}:
+        ours = [e["dur_ns"] for e in sorted(t.events, key=lambda e: e["ts_ns"])
+                if e["name"] == name]
+        theirs = [e - s for n, s, e, _ in events if n == obs.PROFILER_PREFIX + name]
+        assert len(ours) == len(theirs), name
+        # the annotation encloses the span's two clock reads and adds its
+        # own enter and exit: a few microseconds under a profiler that
+        # also traces every Python call
+        for a, b in zip(ours, theirs):
+            assert 0 <= b - a <= max(0.05 * a, 10e3), (name, a, b)
+
+
+def test_traced_count_blocks_once(monkeypatch, small_graphs):
+    import jax
+
+    from repro.core import TriangleCounter
+
+    tc = TriangleCounter(method="wedge_bsearch", max_wedge_chunk=256)
+    want = tc.count(small_graphs["kron"])
+    assert tc.last_stats.n_chunks > 1
+    calls = []
+    block = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready", lambda x: calls.append(1) or block(x))
+    with obs.tracing():
+        assert tc.count(small_graphs["kron"]) == want
+    assert len(calls) == 1
+
+
+def test_dispatch_counters_match_the_plan():
+    import jax
+
+    from repro.core import TriangleCounter
+    from repro.core.engine import PanelBackend, workload_from_csr
+
+    csr = _panel_csr()
+    chunks = list(PanelBackend().plan(workload_from_csr(csr), 1 << 10).chunks)
+    tc = TriangleCounter(method="panel", max_wedge_chunk=1 << 10)
+    with obs.tracing() as t:
+        tc.count(csr)
+    args = {e["name"]: e.get("args", {}) for e in t.events}
+    assert args["engine.dispatch"]["slots"] == sum(2 * len(c.u) * c.width for c in chunks)
+    assert args["engine.dispatch"]["h2d_bytes"] == sum(c.u.nbytes + c.v.nbytes for c in chunks)
+    assert args["engine.dispatch"]["chunks"] == len(chunks) == tc.last_stats.n_chunks
+    resident = (csr.src, csr.col, csr.out_degree)
+    assert all(isinstance(a, jax.Array) for a in resident)
+    assert args["engine.host_copy"]["bytes"] == sum(a.nbytes for a in resident)
+    assert args["engine.plan"] == {"call": args["engine.count"]["call"],
+                                   "edges": csr.n_directed_edges, "chunks": len(chunks)}
+
+
+@pytest.mark.parametrize("kind", ["per_node", "edge_support"])
+def test_per_chunk_phases_of_per_node_and_support(kind, small_graphs):
+    from repro.core import TriangleCounter
+
+    tc = TriangleCounter(method="panel", max_wedge_chunk=256)
+    want = getattr(tc, kind)(small_graphs["kron"])
+    with obs.tracing() as t:
+        got = getattr(tc, kind)(small_graphs["kron"])
+    assert np.array_equal(got, want)
+    n = tc.last_stats.n_chunks
+    assert n > 1
+    names = [e["name"] for e in t.events]
+    # per-node and support fold each chunk before the next one launches
+    for phase in ("dispatch", "chunk", "wait", "fold"):
+        assert names.count(f"engine.{phase}") == n, phase
+    es = tc.last_stats
+    assert tuple(es.timings) == PHASES
+    for phase in ("dispatch", "wait", "fold"):
+        spans = [e["dur_ns"] for e in t.events if e["name"] == f"engine.{phase}"]
+        assert es.timings[phase] == pytest.approx(sum(spans) / 1e9, rel=1e-9)
+    # the untraced answer has the same phases
+    getattr(tc, kind)(small_graphs["kron"])
+    assert tuple(tc.last_stats.timings) == PHASES
