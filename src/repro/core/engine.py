@@ -106,6 +106,7 @@ import itertools
 import math
 import os
 import warnings
+import weakref
 from typing import Iterator, NamedTuple
 
 import jax
@@ -543,7 +544,9 @@ class WorkPlan(NamedTuple):
     """A backend's chunking decision for one workload.
 
     ``timings`` is filled in by ``run_workload`` on the plan it returns
-    (backends leave it at the default): phase → seconds.
+    (backends leave it at the default): phase → seconds.  ``chunks`` is
+    consumed by one run; a kept plan (:class:`_KeptPlans`) holds them as
+    a tuple and hands each answer a fresh iterator.
     """
 
     chunks: Iterator
@@ -725,7 +728,8 @@ class PanelBackend(KernelBackend):
 
     @staticmethod
     def _upload(chunk, *fields):
-        """The chunk's host index arrays, each copied to the device once."""
+        """The chunk's index arrays on the device: host arrays are copied
+        there, a kept plan's device arrays pass through."""
         return tuple(jnp.asarray(getattr(chunk, f)) for f in fields)
 
     def _gather(self, adj, u, v, width):
@@ -781,6 +785,53 @@ class PallasBackend(PanelBackend):
         from repro.kernels.triangle_count import ops as tc_ops
 
         return tc_ops.intersect_support(a, b, tiles=self._tiles(a, b))
+
+
+class _KeptPlans:
+    """A counter's panel plans of resident graphs, each built once.
+
+    The panel plan is a pure function of the query edges, the
+    out-degrees, the width ladder and the budget.  When the workload's
+    arrays are ``jax.Array``s (immutable, unlike host arrays, which can
+    change in place) the plan is kept, with every chunk's ``edge_idx``,
+    ``u`` and ``v`` put on the device once, and later answers on the same
+    arrays take it.  An entry is keyed by the arrays' ``id`` (a
+    ``jax.Array`` is not hashable) and the settings; it holds weak
+    references to the arrays, confirmed on every lookup, whose callbacks
+    drop the entry when an array dies, so a recycled ``id`` never finds
+    a stale plan.
+    """
+
+    def __init__(self):
+        self._entries: dict = {}
+
+    def plan(self, backend: KernelBackend, work: Workload, budget: int | None):
+        """``(plan, reused)``: the kept plan of ``work``, else a new one,
+        kept when the backend and the arrays allow it."""
+        arrays = (work.src_e, work.dst_e, work.out_degree)
+        if not (isinstance(backend, PanelBackend)
+                and all(isinstance(a, jax.Array) for a in arrays)):
+            return backend.plan(work, budget), False
+        key = (*map(id, arrays), backend.widths, budget)
+        entry = self._entries.get(key)
+        if entry is not None and all(r() is a for r, a in zip(entry[0], arrays)):
+            kept = entry[1]
+            return kept._replace(chunks=iter(kept.chunks)), True
+        kept = backend.plan(work, budget)
+        kept = kept._replace(chunks=tuple(
+            c._replace(edge_idx=jnp.asarray(c.edge_idx), u=jnp.asarray(c.u),
+                       v=jnp.asarray(c.v))
+            for c in kept.chunks
+        ))
+        owner = weakref.ref(self)
+
+        def drop(_dead):
+            kept_plans = owner()
+            if kept_plans is not None:
+                kept_plans._entries.pop(key, None)
+
+        self._entries[key] = (tuple(weakref.ref(a, drop) for a in arrays), kept)
+        return kept._replace(chunks=iter(kept.chunks)), False
 
 
 class DistributedBackend(KernelBackend):
@@ -1050,6 +1101,18 @@ def _sanitizer():
     return _rt
 
 
+def _plan_step(work: Workload, ids: dict, make):
+    """An answer's planning: ``make() -> (plan, reused)`` under the
+    ``engine.plan`` span (args ``edges``, ``chunks``, ``reused`` 0/1),
+    counted in ``engine.plans_built`` or ``engine.plans_reused``.
+    Returns ``(plan, seconds)``."""
+    with obs.span("engine.plan", cat="engine", args=ids) as sp:
+        plan, reused = make()
+        sp.set(edges=int(work.src_host.shape[0]), chunks=plan.n_chunks, reused=int(reused))
+    obs.counter("engine.plans_reused" if reused else "engine.plans_built").add()
+    return plan, sp.seconds
+
+
 def run_workload(
     backend: KernelBackend,
     kind: str,
@@ -1059,6 +1122,7 @@ def run_workload(
     n_out: int | None = None,
     bucket_pow2: bool = False,
     call: int | None = None,
+    plan: WorkPlan | None = None,
 ):
     """Plan → launch → accumulate one workload through a backend.
 
@@ -1070,8 +1134,14 @@ def run_workload(
     launch stats (``n_chunks``, ``peak_buffer``, ``total_wedges``) plus
     the phase ``timings``.
 
+    A caller that already holds the workload's plan passes it as ``plan``
+    (:class:`TriangleCounter` keeps a resident graph's panel plan, and
+    plans under its own ``engine.plan`` span); the ``plan`` timing is then
+    0 here.  Otherwise the backend plans the workload.
+
     Each phase is a :mod:`repro.obs` span, and its ``timings`` entry is
-    that span's duration: ``engine.plan`` (args ``edges``, ``chunks``),
+    that span's duration: ``engine.plan`` (args ``edges``, ``chunks``,
+    ``reused``; see :func:`_plan_step`),
     ``engine.dispatch`` (the launch loop, one ``engine.chunk`` per chunk;
     args ``chunks``, ``h2d_bytes`` and, for panel chunks, ``slots``),
     ``engine.wait`` (one ``jax.block_until_ready``) and ``engine.fold``
@@ -1085,10 +1155,10 @@ def run_workload(
         raise ValueError(f"unknown workload kind {kind!r}")
     ids = {} if call is None else {"call": call}
     timings = {"plan": 0.0, "dispatch": 0.0, "wait": 0.0, "fold": 0.0}
-    with obs.span("engine.plan", cat="engine", args=ids) as sp:
-        plan = backend.plan(work, budget, bucket_pow2=bucket_pow2)
-        sp.set(edges=int(work.src_host.shape[0]), chunks=plan.n_chunks)
-    timings["plan"] = sp.seconds
+    if plan is None:
+        plan, timings["plan"] = _plan_step(
+            work, ids, lambda: (backend.plan(work, budget, bucket_pow2=bucket_pow2), False)
+        )
     # a no-op for device-resident adjacency (every engine answer)
     adj = _DeviceAdj(
         jnp.asarray(work.row_offsets), jnp.asarray(work.col),
@@ -1298,6 +1368,12 @@ class TriangleCounter:
     After any call, :attr:`last_stats` holds an :class:`EngineStats`
     describing what ran (resolved method, executed method, chunk count,
     peak buffer, and any capability-fallback reason).
+
+    A panel or Pallas plan of a resident graph (an :class:`OrientedCSR`
+    of ``jax.Array``s) is built by the first answer on it and kept, with
+    its chunk index arrays on the device, for every later answer of any
+    kind on the same arrays (:class:`_KeptPlans`); it is dropped when
+    the arrays die.
     """
 
     def __init__(
@@ -1325,6 +1401,7 @@ class TriangleCounter:
         self.shorter_side = shorter_side
         self.tuner = tuner
         self.last_stats: EngineStats | None = None
+        self._plans = _KeptPlans()
 
     # -- public API ---------------------------------------------------------
 
@@ -1483,16 +1560,21 @@ class TriangleCounter:
             resolved, kind, widths=self.widths, tuner=self.tuner,
             mesh=self.mesh, shorter_side=self.shorter_side,
         )
+        plan, plan_s = _plan_step(
+            work, ids, lambda: self._plans.plan(backend, work, self.max_wedge_chunk)
+        )
         value, plan = run_workload(
             backend, kind, work,
             budget=self.max_wedge_chunk,
             n_out=csr.n_nodes if kind == "per_node" else None,
             call=ids["call"],
+            plan=plan,
         )
         self._record(
             executed, plan.n_chunks, plan.peak_buffer, plan.total_wedges,
             csr.n_directed_edges, resolved=resolved, fallback_reason=reason,
             stripe_loads=plan.stripe_loads, n_stripes=plan.n_stripes,
-            timings={"preprocess": prep_s, "host_copy": host_copy_s, **plan.timings},
+            timings={"preprocess": prep_s, "host_copy": host_copy_s,
+                     **plan.timings, "plan": plan_s},
         )
         return value

@@ -5,9 +5,12 @@ the paper's graph families, and chunked counting (any `max_wedge_chunk`)
 is bit-identical to the unchunked path while the materialized wedge
 buffer never exceeds the budget.
 """
+import gc
+
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import (
     TriangleCounter,
     accumulate_partials,
@@ -15,6 +18,7 @@ from repro.core import (
     count_triangles,
     count_triangles_numpy,
     plan_edge_chunks,
+    prepare_oriented,
     transitivity,
 )
 from repro.core.engine import METHODS
@@ -434,3 +438,116 @@ def test_empty_graph():
     tc = TriangleCounter()
     assert tc.count(np.zeros((0, 2), np.int32)) == 0
     assert tc.per_node(np.zeros((0, 2), np.int32), n_nodes=5).shape == (5,)
+
+
+# ---------------------------------------------------------------------------
+# a resident graph's panel plan, built once and kept
+# ---------------------------------------------------------------------------
+
+
+def _plan_tally():
+    counters = obs.metrics_snapshot()["counters"]
+    return counters.get("engine.plans_built", 0), counters.get("engine.plans_reused", 0)
+
+
+def _dense_oracles(e):
+    """Count (core/baseline.py), per-vertex triangles (diag A³ / 2) and the
+    support of every oriented edge (A² at it) of a small graph."""
+    n = int(e.max()) + 1
+    a = np.zeros((n, n), np.int64)
+    a[e[:, 0], e[:, 1]] = 1
+    a2 = a @ a
+    return count_triangles_numpy(e), np.diag(a2 @ a) // 2, a2
+
+
+@pytest.fixture(scope="module")
+def resident_kron():
+    e = kronecker_rmat(8, seed=0)
+    return e, _dense_oracles(e)
+
+
+@pytest.mark.parametrize("kind", ["count", "per_node", "edge_support"])
+@pytest.mark.parametrize("method", ["panel", "pallas"])
+def test_resident_graph_is_planned_once(method, kind, resident_kron):
+    e, (count, per_node, a2) = resident_kron
+    csr = prepare_oriented(e)
+    want = {"count": count, "per_node": per_node,
+            "edge_support": a2[np.asarray(csr.src), np.asarray(csr.col)]}[kind]
+    tc = TriangleCounter(method=method, max_wedge_chunk=1 << 10)
+    built, reused = _plan_tally()
+    with obs.tracing() as t:
+        answers = [getattr(tc, kind)(csr) for _ in range(3)]
+    for got in answers:
+        assert np.array_equal(got, want)
+        assert np.array_equal(got, answers[0])
+    assert tc.last_stats.n_chunks > 1
+    assert _plan_tally() == (built + 1, reused + 2)
+    plans = [ev["args"] for ev in t.events if ev["name"] == "engine.plan"]
+    assert [p["reused"] for p in plans] == [0, 1, 1]
+    # the chunk index arrays went to the device with the kept plan
+    h2d = [ev["args"]["h2d_bytes"] for ev in t.events if ev["name"] == "engine.dispatch"]
+    assert h2d and not any(h2d)
+
+
+def test_kept_plan_dies_with_its_graph(resident_kron):
+    e, (count, _, _) = resident_kron
+    perm = np.random.default_rng(0).permutation(int(e.max()) + 1)
+    e_b = perm[e]
+    _, per_node_b, _ = _dense_oracles(e_b)
+    tc = TriangleCounter(method="panel", max_wedge_chunk=1 << 10)
+    built, reused = _plan_tally()
+    csr_a = prepare_oriented(e)
+    assert tc.count(csr_a) == count
+    shapes = [a.shape for a in csr_a]
+    del csr_a
+    gc.collect()
+    assert not tc._plans._entries
+    csr_b = prepare_oriented(e_b)
+    assert [a.shape for a in csr_b] == shapes
+    assert not np.array_equal(np.asarray(csr_b.col), np.asarray(prepare_oriented(e).col))
+    assert tc.count(csr_b) == count_triangles_numpy(e_b)
+    assert np.array_equal(tc.per_node(csr_b), per_node_b)
+    assert _plan_tally() == (built + 2, reused + 1)
+
+
+def test_host_workloads_never_reuse_a_plan(resident_kron):
+    import jax
+
+    from repro.core import IncrementalTriangleCounter
+    from repro.core.engine import PanelBackend, make_workload, run_workload
+
+    e, (count, _, _) = resident_kron
+    csr = prepare_oriented(e)
+    _, reused = _plan_tally()
+    host = make_workload(*(np.asarray(a) for a in (csr.row_offsets, csr.col,
+                                                    csr.out_degree, csr.src, csr.col)))
+    for _ in range(2):
+        assert run_workload(PanelBackend(), "count", host, budget=1 << 10)[0] == count
+    # a NumPy edge array is oriented anew on every call
+    tc = TriangleCounter(method="panel", max_wedge_chunk=1 << 10)
+    assert tc.count(e) == tc.count(e) == count
+    # the wedge and striped schedules plan every answer
+    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    for other in (TriangleCounter(method="wedge_bsearch", max_wedge_chunk=1 << 10),
+                  TriangleCounter(method="distributed", mesh=mesh)):
+        assert other.count(csr) == other.count(csr) == count
+    itc = IncrementalTriangleCounter(method="panel", max_wedge_chunk=1 << 10)
+    for batch in np.array_split(e[e[:, 0] < e[:, 1]], 2):
+        itc.insert(batch)
+    assert itc.count == count
+    assert itc.last_update_stats.probe_method == "panel"
+    assert _plan_tally()[1] == reused
+
+
+def test_kept_plans_follow_the_budget(resident_kron):
+    from repro.core.engine import PanelBackend, workload_from_csr
+
+    e, (count, _, _) = resident_kron
+    csr = prepare_oriented(e)
+    for budget in (1 << 10, 1 << 12):
+        want = PanelBackend().plan(workload_from_csr(csr), budget).n_chunks
+        tc = TriangleCounter(method="panel", max_wedge_chunk=budget)
+        for _ in range(2):
+            assert tc.count(csr) == count
+            assert tc.last_stats.n_chunks == want
+    assert PanelBackend().plan(workload_from_csr(csr), 1 << 10).n_chunks > want

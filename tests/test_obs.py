@@ -383,7 +383,9 @@ def test_profiler_trace_holds_nested_engine_spans(tmp_path):
     for _, s, e, st in by["tc.engine.chunk"]:
         assert dispatch[1] <= s and e <= dispatch[2]
         assert st["width"] in (16, 64) and st["rows"] > 0
-    assert dispatch[3]["slots"] > 0 and dispatch[3]["h2d_bytes"] > 0
+    # the warm answer kept the plan, with its index arrays on the device
+    assert plan[3]["reused"] == 1
+    assert dispatch[3]["slots"] > 0 and dispatch[3]["h2d_bytes"] == 0
     assert by["tc.engine.host_copy"][0][3]["bytes"] > 0
     assert by["tc.engine.fold"][0][3]["bytes"] > 0
     # the phases follow one another in answer order
@@ -437,7 +439,7 @@ def test_dispatch_counters_match_the_plan():
     import jax
 
     from repro.core import TriangleCounter
-    from repro.core.engine import PanelBackend, workload_from_csr
+    from repro.core.engine import PanelBackend, make_workload, run_workload, workload_from_csr
 
     csr = _panel_csr()
     chunks = list(PanelBackend().plan(workload_from_csr(csr), 1 << 10).chunks)
@@ -446,13 +448,24 @@ def test_dispatch_counters_match_the_plan():
         tc.count(csr)
     args = {e["name"]: e.get("args", {}) for e in t.events}
     assert args["engine.dispatch"]["slots"] == sum(2 * len(c.u) * c.width for c in chunks)
-    assert args["engine.dispatch"]["h2d_bytes"] == sum(c.u.nbytes + c.v.nbytes for c in chunks)
+    # a resident graph's kept plan put its index arrays on the device
+    # when it was built: the launches upload nothing
+    assert args["engine.dispatch"]["h2d_bytes"] == 0
     assert args["engine.dispatch"]["chunks"] == len(chunks) == tc.last_stats.n_chunks
     resident = (csr.src, csr.col, csr.out_degree)
     assert all(isinstance(a, jax.Array) for a in resident)
     assert args["engine.host_copy"]["bytes"] == sum(a.nbytes for a in resident)
     assert args["engine.plan"] == {"call": args["engine.count"]["call"],
-                                   "edges": csr.n_directed_edges, "chunks": len(chunks)}
+                                   "edges": csr.n_directed_edges, "chunks": len(chunks),
+                                   "reused": 0}
+    # a workload of host arrays uploads each chunk's u and v as it launches
+    host = make_workload(*(np.asarray(a) for a in (csr.row_offsets, csr.col, csr.out_degree,
+                                                   csr.src, csr.col)))
+    with obs.tracing() as t:
+        run_workload(PanelBackend(), "count", host, budget=1 << 10)
+    args = {e["name"]: e.get("args", {}) for e in t.events}
+    assert args["engine.dispatch"]["h2d_bytes"] == sum(c.u.nbytes + c.v.nbytes for c in chunks)
+    assert args["engine.plan"]["reused"] == 0
 
 
 @pytest.mark.parametrize("kind", ["per_node", "edge_support"])
