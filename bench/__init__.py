@@ -4,11 +4,13 @@
 package holds everything else, each part found by its name:
 
 - ``run.py``: one run of one cell (``python3 -m bench.run --help``);
-- ``configs/<config>.json``: a deployment, its source and its cuts;
+- ``configs/<config>.json``: a deployment, its source, its cuts and its
+  small stand-in for the self-tests (``small``);
 - ``graphs/<graph>.py``: a graph family's generator;
-- ``traffic/<traffic>.json``: a traffic mix, naming its loop and answer kind;
-- ``loops/<loop>.py``: an arrival pattern; ``answers/<answer>.py``: a kind
-  of answer, its reference, comparison and control;
+- ``traffic/<traffic>.json``: a traffic mix, naming its loop and answer kinds;
+- ``loops/<loop>.py``: an arrival pattern and the deployment it drives;
+  ``answers/<answer>.py``: a kind of answer, its reference, comparison
+  and control;
 - ``metrics/<metric>.py``: how one metric is read from a run;
 - ``reference.py``, ``work.py``, ``trace.py``, ``peaks.json``: the
   yardstick: the plain reference, the bytes and compares of an answer,

@@ -4,44 +4,49 @@ compares the program's.  Every reading has to fail its limit.
 
     python3 -m bench.control --workload kron17.count --seeds 11 12 13
 
-Each seed gives the cell's graph relabelled as a run relabels it; the
-answer kind's ``control`` (``bench/answers/``) answers once and the
-reference once.  One JSON line per seed.  The benchmark's runs never
-call this; ``bench/tests/test_controls.py`` does at a small size.
+Each seed gives the cell's graphs relabelled as a run relabels them
+(the loop's ``graphs``); on each graph, each answer kind of the traffic
+mix has its ``control`` (``bench/answers/``) answer once and the
+reference once, and each number compared is the largest over them.  One
+JSON line per seed.  The benchmark's runs never call this;
+``bench/tests/test_controls.py`` does at a small size.
 """
 from __future__ import annotations
 
 import argparse
 import importlib
 import json
-import os
 import sys
 import time
 
 from bench import run as harness
 
 
+def kinds(traffic: dict) -> list:
+    """The answer kinds a traffic mix asks for."""
+    if "mix" in traffic:
+        return [k for k, share in traffic["mix"].items() if share > 0]
+    return [traffic["answer"]]
+
+
 def read_controls(spec: dict, workload: str, seeds, *, config: dict | None = None,
-                  require_tpu: bool = True) -> list:
+                  traffic: dict | None = None, require_tpu: bool = True) -> list:
     cell = harness.by_name(spec["workloads"], workload)
-    if config is None:
-        config = harness.load_json(os.path.join(
-            harness.ROOT, harness.by_name(spec["configs"], cell["config"])["file"]))
-    traffic = harness.load_json(os.path.join(harness.ROOT, "bench", "traffic",
-                                             f"{cell['traffic']}.json"))
+    config, traffic = harness.cell_files(spec, cell, config, traffic)
     harness.devices(cell["chips"], require_tpu)
     harness.use_compile_cache()
-    from bench import graphs
-
-    answer = importlib.import_module(f"bench.answers.{traffic['answer']}")
-    base, n_nodes = graphs.generate(config)
+    loop = importlib.import_module(f"bench.loops.{traffic['loop']}")
     readings = []
     for seed in seeds:
-        edges = graphs.relabel(base, n_nodes, seed)
-        t0 = time.perf_counter()
-        value = answer.control(edges, n_nodes, seed, traffic["counter"])
-        control_s = time.perf_counter() - t0
-        _, compared = answer.compare([value], answer.reference(edges, n_nodes))
+        control_s, compared = 0.0, {}
+        for edges, n_nodes in loop.graphs(config, seed).values():
+            for kind in kinds(traffic):
+                answer = importlib.import_module(f"bench.answers.{kind}")
+                t0 = time.perf_counter()
+                value = answer.control(edges, n_nodes, seed, traffic["counter"])
+                control_s += time.perf_counter() - t0
+                _, more = answer.compare([value], answer.reference(edges, n_nodes))
+                compared = harness.merge(compared, more)
         readings.append({"workload": workload, "seed": seed, "control_s": control_s,
                          "compared": {k: {"value": v, "limit": lim}
                                       for k, (v, lim) in compared.items()},

@@ -5,26 +5,29 @@
 
 The cell, its configuration and its traffic mix are looked up by name:
 the cell in ``BENCHMARK.json``, the configuration in the file it names,
-the traffic in ``bench/traffic/<traffic>.json``, which names its answer
-kind (``bench/answers/``) and its arrival loop (``bench/loops/``), and
-every metric in ``bench/metrics/<name>.py``.
+the traffic in ``bench/traffic/<traffic>.json``, which names its arrival
+loop (``bench/loops/``), and every metric in ``bench/metrics/<name>.py``.
 
 A run:
 
-1. generates the configuration's graph with the benchmark's own
-   generator, and relabels its vertices from ``--seed``;
-2. orients it on the device once (``repro.core.prepare_oriented``) and
-   keeps the ``OrientedCSR`` resident;
-3. warms every shape up with one full answer;
-4. answers for ``--seconds`` (``--trace 1``: under the profiler), then
+1. has the loop set its deployment up from the configuration, the traffic
+   and ``--seed``: the graphs, generated by the benchmark's own
+   generators and relabelled from the seed, and the program that answers
+   on them (``bench/loops/closed.py``: one resident ``OrientedCSR`` and a
+   ``TriangleCounter``; ``bench/loops/open.py``: a ``GraphService``);
+2. warms every shape up;
+3. measures for ``--seconds`` (``--trace 1``: under the profiler), then
    reads the device's peak memory, frees the program's state, computes
-   the reference and compares every answer of the window with it.
+   the reference of each graph and answer kind the window asked, and
+   compares every answer of the window with it
+   (``bench/answers/<kind>.py``).
 
 Standard error gets what the run saw (set-up steps, compiles in the
-window, peak memory, chunks and compares per answer, the trace) and, as
-its last lines, each number compared beside its limit.  The last line
-of standard output is the result.  Without a TPU, or with fewer chips
-than the cell asks for, the run exits with code 2 and prints no result.
+window, peak memory, the deployment's own line, the work per answer on
+each graph, the trace) and, as its last lines, each number compared
+beside its limit.  The last line of standard output is the result.
+Without a TPU, or with fewer chips than the cell asks for, the run exits
+with code 2 and prints no result.
 """
 from __future__ import annotations
 
@@ -35,6 +38,7 @@ T_START = time.perf_counter()  # process start, as far as set-up is concerned
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
 import dataclasses  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import importlib  # noqa: E402
 import json  # noqa: E402
@@ -64,8 +68,15 @@ class Run:
     latencies: list
     plan_s: list
     device_kind: str
-    work: dict
+    work: dict | None           # one answer's work (bench/work.py) where all do the same
     trace: object = None        # bench.trace.Summary of a traced window
+    window_work: dict | None = None   # the work summed over the window's answers
+
+    def total(self, key: str) -> int:
+        """``key``'s work over the window: ``window_work``, or answers x ``work``."""
+        if self.window_work is not None:
+            return self.window_work[key]
+        return len(self.latencies) * self.work[key]
 
 
 def log(msg: str) -> None:
@@ -145,47 +156,56 @@ def step(name: str, seconds: dict):
     t0 = time.perf_counter()
     with jax.profiler.TraceAnnotation(f"bench.setup.{name}"):
         yield
-    seconds[name] = time.perf_counter() - t0
+    seconds[name] = seconds.get(name, 0.0) + time.perf_counter() - t0
+
+
+def merge(compared: dict, more: dict) -> dict:
+    """Compared numbers of two groups of answers: each the larger value."""
+    out = dict(compared)
+    for k, (v, limit) in more.items():
+        out[k] = (max(v, out[k][0]), limit) if k in out else (v, limit)
+    return out
+
+
+def work_of(edges, n_nodes) -> dict:
+    """One answer's work on a graph (``bench/work.py``)."""
+    from bench import work
+
+    out_deg, src, dst = work.oriented_edges(edges, n_nodes)
+    return {"intersection_bytes": work.intersection_bytes(out_deg, src, dst),
+            "compares": work.intersection_compares(out_deg, src, dst),
+            "padded_compares": work.padded_compares(out_deg, src, dst),
+            "max_out_degree": int(out_deg.max())}
 
 
 def run_cell(spec: dict, workload: str, seed: int, seconds: float, trace: bool, *,
-             config: dict | None = None, require_tpu: bool = True) -> dict:
+             config: dict | None = None, traffic: dict | None = None,
+             require_tpu: bool = True) -> dict:
     """One run of one cell; returns the result object.
 
-    ``config`` replaces the cell's configuration and ``require_tpu=False``
-    skips the look for a chip: both for the tests alone.
+    ``config`` and ``traffic`` replace the cell's configuration and traffic
+    mix, and ``require_tpu=False`` skips the look for a chip: all for the
+    tests alone.
     """
-    from repro.core import TriangleCounter, prepare_oriented
+    import repro.core  # noqa: F401  (the system under test: without it, no run)
 
     setup = {"import": time.perf_counter() - T_START}
     cell = by_name(spec["workloads"], workload)
-    if config is None:
-        config = load_json(os.path.join(ROOT, by_name(spec["configs"], cell["config"])["file"]))
-    traffic = load_json(os.path.join(ROOT, "bench", "traffic", f"{cell['traffic']}.json"))
+    config, traffic = cell_files(spec, cell, config, traffic)
     t0 = time.perf_counter()
     devs = devices(cell["chips"], require_tpu)
     setup["attach"] = time.perf_counter() - t0
 
     import jax
 
-    from bench import graphs, work
-
     use_compile_cache()
     compiles = CompileCounter()
     jax.monitoring.register_event_duration_secs_listener(compiles)
-    answer = importlib.import_module(f"bench.answers.{traffic['answer']}")
     loop = importlib.import_module(f"bench.loops.{traffic['loop']}")
 
-    with step("generate", setup):
-        base, n_nodes = graphs.generate(config)
-    with step("relabel", setup):
-        edges = graphs.relabel(base, n_nodes, seed)
-        del base
-    with step("orient", setup):
-        csr = jax.block_until_ready(prepare_oriented(edges, n_nodes))
-    counter = TriangleCounter(**traffic["counter"])
+    deployment = loop.setup(config, seed, traffic, functools.partial(step, seconds=setup))
     with step("warmup", setup):
-        loop.warm(answer, counter, csr, traffic)
+        deployment.warm()
     log("set-up " + " ".join(f"{k}={v:.3f}s" for k, v in setup.items()))
 
     trace_dir = os.path.join(CACHE, "trace", workload)
@@ -199,37 +219,38 @@ def run_cell(spec: dict, workload: str, seed: int, seconds: float, trace: bool, 
     # the program, and varies by seconds from run to run: not set-up
     setup_s = time.perf_counter() - T_START - setup["attach"]
     try:
-        window = loop.run(answer, counter, csr, seconds, traffic)
+        window = deployment.run(seconds)
     finally:
         compiles.active = False
         if trace:
             jax.profiler.stop_trace()
-    stats = counter.last_stats
     peak = (devs[0].memory_stats() or {}).get("peak_bytes_in_use")
-    log(f"window {len(window.answers)} answers in {window.window_s:.6f}s; "
-        f"in the window {compiles.counts['traces']} traces, "
+    log(f"window {len(window.answers)} answers, {window.failed} failed, in "
+        f"{window.window_s:.6f}s; in the window {compiles.counts['traces']} traces, "
         f"{compiles.counts['compiles']} compiles; peak_bytes_in_use={peak}")
-    log(f"per answer: method={stats.method} chunks={stats.n_chunks} "
-        f"peak_wedge_buffer={stats.peak_wedge_buffer}")
-    del csr, counter
+    log(deployment.describe())
+    graphs = deployment.graphs
+    deployment.close()
+    del deployment
     gc.collect()
 
-    out_deg, src, dst = work.oriented_edges(edges, n_nodes)
-    counts = {"intersection_bytes": work.intersection_bytes(out_deg, src, dst),
-              "compares": work.intersection_compares(out_deg, src, dst),
-              "padded_compares": work.padded_compares(out_deg, src, dst)}
-    log(f"graph n={n_nodes} undirected_edges={edges.shape[0] // 2} "
-        f"max_out_degree={int(out_deg.max())}; per answer " +
-        " ".join(f"{k}={v}" for k, v in counts.items()))
+    asked = sorted({a.graph for a in window.answers})
+    per_graph = {name: work_of(*graphs[name]) for name in asked}
+    for name in asked:
+        edges, n_nodes = graphs[name]
+        log(f"graph {name} n={n_nodes} undirected_edges={edges.shape[0] // 2}; per answer " +
+            " ".join(f"{k}={v}" for k, v in per_graph[name].items()))
+    window_work = {k: sum(per_graph[a.graph][k] for a in window.answers)
+                   for k in ("intersection_bytes", "compares", "padded_compares")}
 
     t0 = time.perf_counter()
-    ref = answer.reference(edges, n_nodes)
-    failed, compared = answer.compare(window.answers, ref)
-    log(f"reference took {time.perf_counter() - t0:.3f}s" +
-        (f"; triangles={ref}" if isinstance(ref, int) else ""))
+    failed, compared = compare_window(window.answers, graphs)
+    log(f"reference took {time.perf_counter() - t0:.3f}s")
 
     run = Run(setup_s=setup_s, window_s=window.window_s, latencies=window.latencies,
-              plan_s=window.plan_s, device_kind=devs[0].device_kind, work=counts)
+              plan_s=window.plan_s, device_kind=devs[0].device_kind,
+              work=per_graph[asked[0]] if len(asked) == 1 else None,
+              window_work=window_work)
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
               "count": len(devs), "memory_peak_bytes": peak}
     breakdown = {}
@@ -250,19 +271,56 @@ def run_cell(spec: dict, workload: str, seed: int, seconds: float, trace: bool, 
         value = importlib.import_module(f"bench.metrics.{m['name']}").read(run)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
-    correct = bool(window.answers) and all(v <= limit for v, limit in compared.values())
-    result = {"correct": correct, "attempted": len(window.answers), "failed": failed,
-              "metrics": metrics, "device": device, **breakdown,
+    correct = (bool(window.answers) and window.failed == 0
+               and all(v <= limit for v, limit in compared.values()))
+    result = {"correct": correct, "attempted": len(window.answers) + window.failed,
+              "failed": failed + window.failed, "metrics": metrics, "device": device,
+              **breakdown,
               "compared": {k: {"value": v, "limit": limit} for k, (v, limit) in compared.items()}}
     record = dict(workload=workload, seed=seed, seconds=seconds, trace=trace, setup=setup,
-                  latencies=window.latencies, plan_s=window.plan_s, compiles=compiles.counts,
-                  work=counts, result=result)
+                  answers=[{"graph": a.graph, "kind": a.kind, "latency_s": a.latency_s}
+                           for a in window.answers],
+                  plan_s=window.plan_s, compiles=compiles.counts, work=per_graph,
+                  window_work=window_work, result=result)
+    if trace:
+        from bench import spans
+
+        s = spans.of(run)
+        record["span_idle_s"] = s.span_idle_s if s else None
     os.makedirs(os.path.join(CACHE, "runs"), exist_ok=True)
     with open(os.path.join(CACHE, "runs", f"{workload}.{seed}.{int(trace)}.json"), "w") as f:
         json.dump(record, f)
     for k, (v, limit) in compared.items():
         log(f"compared {k}={v} limit={limit}")
     return result
+
+
+def cell_files(spec: dict, cell: dict, config: dict | None, traffic: dict | None) -> tuple:
+    """The cell's configuration and traffic mix, unless given."""
+    if config is None:
+        config = load_json(os.path.join(ROOT, by_name(spec["configs"], cell["config"])["file"]))
+    if traffic is None:
+        traffic = load_json(os.path.join(ROOT, "bench", "traffic", f"{cell['traffic']}.json"))
+    return config, traffic
+
+
+def compare_window(answers: list, graphs: dict) -> tuple:
+    """``(failed, compared)`` of the window's answers: each graph and answer
+    kind's reference computed once, each group compared by its kind's
+    ``compare``, failures summed and each compared number the largest."""
+    groups = {}
+    for a in answers:
+        groups.setdefault((a.graph, a.kind), []).append(a.value)
+    failed, compared = 0, {}
+    for (graph, kind), values in sorted(groups.items()):
+        answer = importlib.import_module(f"bench.answers.{kind}")
+        ref = answer.reference(*graphs[graph])
+        if isinstance(ref, int):
+            log(f"reference {graph} {kind}: {ref}")
+        n_failed, more = answer.compare(values, ref)
+        failed += n_failed
+        compared = merge(compared, more)
+    return failed, compared
 
 
 def main(argv=None) -> int:

@@ -1,16 +1,13 @@
-"""Small stand-ins for the configurations, and the cells the self-tests
-run on the CPU: every cell of ``BENCHMARK.json`` and every traffic mix
-kept for a later cell."""
+"""The cells the self-tests run on the CPU, every cell of
+``BENCHMARK.json`` and every traffic mix kept for a later cell, each on
+its configuration's small stand-in: the ``small`` block of the
+configuration's file, which takes the place of the keys it names."""
+import os
+
 import pytest
 
 from bench import run as harness
 
-SMALL = {
-    "kron17": {"graph": "kronecker",
-               "params": {"scale": 8, "edge_factor": 16, "a": 0.57, "b": 0.19, "c": 0.19,
-                          "seed": 1503}},
-    "rgg20": {"graph": "rgg", "params": {"n_log2": 11, "radius_factor": 0.55, "seed": 0}},
-}
 KEPT = [{"name": "kron17.lcc", "config": "kron17", "traffic": "lcc", "chips": 1}]
 SPEC = harness.load_spec()
 TEST_SPEC = dict(SPEC, workloads=SPEC["workloads"] + [
@@ -18,8 +15,17 @@ TEST_SPEC = dict(SPEC, workloads=SPEC["workloads"] + [
 WORKLOADS = [w["name"] for w in TEST_SPEC["workloads"]]
 
 
+def small(config: dict) -> dict:
+    """The configuration with its small stand-in in place."""
+    out = {k: v for k, v in config.items() if k != "small"}
+    out.update(config["small"])
+    return out
+
+
 def small_config(workload: str) -> dict:
-    return SMALL[harness.by_name(TEST_SPEC["workloads"], workload)["config"]]
+    name = harness.by_name(TEST_SPEC["workloads"], workload)["config"]
+    path = harness.by_name(TEST_SPEC["configs"], name)["file"]
+    return small(harness.load_json(os.path.join(harness.ROOT, path)))
 
 
 @pytest.fixture

@@ -6,11 +6,14 @@ produced (the kernel's per-edge counts), half of every chunk left out,
 and the work skipped so that the answer's accumulator never moves.  No
 cell exchanges data between chips, so that fault has no test.
 """
+import json
+import os
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import run as harness
+from bench import graphs, run as harness
 from bench.tests.small import TEST_SPEC, WORKLOADS, pallas, small_config  # noqa: F401
 from repro.core.engine import PallasBackend, PanelChunk
 
@@ -50,8 +53,11 @@ def unchanged(monkeypatch):
                         lambda self, adj, chunk, n_out: jnp.zeros((n_out,), jnp.int32))
 
 
+SEED = 2**31 + 9
+
+
 def run_small(workload):
-    return harness.run_cell(TEST_SPEC, workload, 2**31 + 9, 0.0, False,
+    return harness.run_cell(TEST_SPEC, workload, SEED, 0.0, False,
                             config=small_config(workload), require_tpu=False)
 
 
@@ -60,6 +66,22 @@ def test_sound_run_is_correct(workload, pallas):  # noqa: F811
     result = run_small(workload)
     assert result["correct"] and result["failed"] == 0
     assert list(result)[-1] == "compared"
+    kind = harness.load_json(os.path.join(
+        harness.ROOT, "bench", "traffic",
+        f"{harness.by_name(TEST_SPEC['workloads'], workload)['traffic']}.json"))["answer"]
+    gap = {"count": "count_gap", "clustering": "lcc_gap"}[kind]
+    assert result["compared"] == {gap: {"value": 0, "limit": 0}}
+    # the window's work is its answers times one answer's on the one graph
+    config = small_config(workload)
+    base, n_nodes = graphs.generate(config)
+    one = harness.work_of(graphs.relabel(base, n_nodes, SEED), n_nodes)
+    with open(os.path.join(harness.CACHE, "runs", f"{workload}.{SEED}.0.json")) as f:
+        record = json.load(f)
+    assert record["work"] == {config["name"]: one}
+    assert {a["graph"] for a in record["answers"]} == {config["name"]}
+    assert {a["kind"] for a in record["answers"]} == {kind}
+    for key in ("intersection_bytes", "compares", "padded_compares"):
+        assert record["window_work"][key] == result["attempted"] * one[key]
 
 
 @pytest.mark.parametrize("fault", [altered, half_left_out, unchanged])

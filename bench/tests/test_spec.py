@@ -6,8 +6,10 @@ import re
 
 import pytest
 
-from bench import run as harness
+from bench import graphs, run as harness
+from bench.control import kinds
 from bench.tests.conftest import ROOT
+from bench.tests.small import small
 
 SPEC = harness.load_spec()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -64,6 +66,18 @@ def test_configs():
         assert all(NAME.match(k) for k in c["reduced"])
 
 
+@pytest.mark.parametrize("config", [c["name"] for c in SPEC["configs"]])
+def test_config_has_a_small_stand_in(config):
+    """Every configuration's ``small`` block, which the self-tests run in
+    its place, names only graphs its generators accept, at a small size."""
+    with open(os.path.join(ROOT, harness.by_name(SPEC["configs"], config)["file"])) as f:
+        stand_in = small(json.load(f))
+    for spec in stand_in.get("tenants", [stand_in]):
+        edges, n_nodes = graphs.generate(spec)
+        assert 0 < edges.shape[0] <= 1 << 20 and 0 < n_nodes <= 1 << 16
+        assert edges.max() < n_nodes
+
+
 def test_workloads_find_their_files():
     pairs = set()
     configs = {c["name"] for c in SPEC["configs"]}
@@ -73,7 +87,8 @@ def test_workloads_find_their_files():
         pairs.add((w["config"], w["traffic"]))
         with open(os.path.join(ROOT, "bench", "traffic", f"{w['traffic']}.json")) as f:
             traffic = json.load(f)
-        assert os.path.exists(os.path.join(ROOT, "bench", "answers", f"{traffic['answer']}.py"))
+        for kind in kinds(traffic):
+            assert os.path.exists(os.path.join(ROOT, "bench", "answers", f"{kind}.py"))
         assert os.path.exists(os.path.join(ROOT, "bench", "loops", f"{traffic['loop']}.py"))
     assert sum(w["chips"] == 4 for w in SPEC["workloads"]) <= max(1, len(SPEC["workloads"]) // 2)
 
