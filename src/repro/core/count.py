@@ -340,6 +340,54 @@ def gather_panel_rows(col: jax.Array, base: jax.Array, length: jax.Array, width:
     )
 
 
+def add_elements(out: jax.Array, base: jax.Array, rows: jax.Array):
+    """``out[base[i] + j] += rows[i, j]`` for every row ``i`` and lane ``j``.
+
+    One single-element scatter-add of ``B·w`` slots; lanes that fall past
+    ``out``'s end must carry zero.  The window scatter of every backend
+    but the TPU (see :func:`add_panel_rows`).
+    """
+    lane = jnp.arange(rows.shape[1], dtype=jnp.int32)
+    idx = jnp.clip(base[:, None] + lane[None, :], 0, out.shape[0] - 1)
+    return out.at[idx].add(rows)
+
+
+def add_windows(out: jax.Array, base: jax.Array, rows: jax.Array):
+    """:func:`add_elements` as whole 128-lane rows: the mirror of
+    :func:`gather_windows`.
+
+    Each ``(w,)`` row is padded to ``k`` 128-lane rows, shifted right by
+    ``base % 128`` with a seven-step barrel shift of static lane rolls,
+    and added to ``k`` whole rows of ``out`` viewed as ``(·, 128)`` — a
+    ``(B, k)`` row-index scatter-add, the embedding-gradient form.
+    Lanes that fall past ``out``'s end land in padding and are dropped.
+    """
+    m, width = out.shape[0], rows.shape[1]
+    k = -(-width // LANES) + 1   # rows covering any window of `width` slots
+    n_rows = -(-m // LANES) + k
+    x = jnp.pad(rows, ((0, 0), (0, k * LANES - width)))
+    base = jnp.clip(base, 0, m)
+    shift = base % LANES
+    for bit in range(LANES.bit_length() - 1):
+        moved = jnp.roll(x, 1 << bit, axis=1)
+        x = jnp.where(((shift >> bit) & 1)[:, None] == 1, moved, x)
+    row = base // LANES
+    rows2d = jnp.pad(out, (0, n_rows * LANES - m)).reshape(n_rows, LANES)
+    rows2d = rows2d.at[row[:, None] + jnp.arange(k, dtype=row.dtype)[None, :]].add(
+        x.reshape(base.shape[0], k, LANES))
+    return rows2d.reshape(-1)[:m]
+
+
+def add_panel_rows(out: jax.Array, base: jax.Array, rows: jax.Array):
+    """The per-edge support scatter's window add, chosen by the platform
+    the program is lowered for, as :func:`gather_panel_rows` chooses the
+    gather: a TPU runs :func:`add_windows`, every other backend
+    :func:`add_elements`.  Both return the same array."""
+    return jax.lax.platform_dependent(
+        out, base, rows, tpu=add_windows, default=add_elements,
+    )
+
+
 @functools.partial(jax.jit, static_argnames=("width",))
 def gather_panels(csr: OrientedCSR, edge_idx: jax.Array, width: int):
     """Gather fixed-width neighbor panels for a bucket of edges.

@@ -116,6 +116,7 @@ import numpy as np
 from repro import obs
 
 from .count import (
+    add_panel_rows,
     expand_and_close_wedges,
     expand_and_close_wedges_indexed,
     gather_panels_arrays,
@@ -364,26 +365,25 @@ def _panel_scatter_per_node(u, v, a, count, arm, *, n_out):
 
 
 @functools.partial(jax.jit, static_argnames=("m_out",))
-def _panel_scatter_support(edge_idx, u, v, row_offsets, count, arm, closure, *, m_out):
+def support_scatter(edge_idx, u, v, row_offsets, count, arm, closure, *, m_out):
     """Scatter (count, arm, closure) to the three directed-edge slots.
 
     Base ``(u, v)`` is the chunk's global query id; arm slot ``j`` is
     directed edge ``row_offsets[u] + j`` (the wedge arm ``(u, w)``);
     closure slot ``k`` is ``row_offsets[v] + k`` (the closing edge
     ``(v, w)``).  Lanes past a row's true length carry zero counts, so
-    their clipped indices are harmless.
+    their adds are harmless.  A chunk of ``B`` rows scatters
+    ``B × (1 + wu + wv)`` lanes, padded rows included; a TPU adds the
+    arm and closure rows as whole 128-lane rows (:func:`add_panel_rows`).
+    Its device operations read ``jit_support_scatter/...`` in a profiler
+    trace.
     """
     out = jnp.zeros((m_out,), jnp.int32)
     out = out.at[jnp.clip(edge_idx, 0, m_out - 1)].add(
         jnp.where(edge_idx >= 0, count, 0)
     )
-    lane_u = jnp.arange(arm.shape[1], dtype=jnp.int32)
-    base_u = row_offsets[jnp.maximum(u, 0)][:, None]
-    out = out.at[jnp.clip(base_u + lane_u[None, :], 0, m_out - 1)].add(arm)
-    lane_v = jnp.arange(closure.shape[1], dtype=jnp.int32)
-    base_v = row_offsets[jnp.maximum(v, 0)][:, None]
-    out = out.at[jnp.clip(base_v + lane_v[None, :], 0, m_out - 1)].add(closure)
-    return out
+    out = add_panel_rows(out, row_offsets[jnp.maximum(u, 0)], arm)
+    return add_panel_rows(out, row_offsets[jnp.maximum(v, 0)], closure)
 
 
 def search_steps(csr: OrientedCSR) -> int:
@@ -750,7 +750,7 @@ class PanelBackend(KernelBackend):
         edge_idx, u, v = self._upload(chunk, "edge_idx", "u", "v")
         a, b, _, _ = self._gather(adj, u, v, chunk.width)
         count, arm, closure = self.intersect_support(a, b)
-        return _panel_scatter_support(
+        return support_scatter(
             edge_idx, u, v, adj.row_offsets, count, arm, closure, m_out=m_out,
         )
 
@@ -1143,13 +1143,16 @@ def run_workload(
     that span's duration: ``engine.plan`` (args ``edges``, ``chunks``,
     ``reused``; see :func:`_plan_step`),
     ``engine.dispatch`` (the launch loop, one ``engine.chunk`` per chunk;
-    args ``chunks``, ``h2d_bytes`` and, for panel chunks, ``slots``),
+    args ``chunks``, ``h2d_bytes`` and, for panel chunks, ``slots``, and
+    for support panel chunks ``scatter_lanes``, also added to the
+    ``engine.scatter_lanes`` counter),
     ``engine.wait`` (one ``jax.block_until_ready``) and ``engine.fold``
     (the host copy and int64/uint64 sum of the partials; arg ``bytes``).
-    A count launches every chunk, waits once and folds once; per-node and
-    support fold each chunk's full-length partial before the next chunk
-    launches, so they run the three phases once per chunk.  ``call``, when
-    given, tags every span with the caller's answer number.
+    A count launches every chunk, waits once and folds once; so does
+    support, whose full-length int32 partials add up on the device as
+    they come.  Per-node folds each chunk's full-length partial before
+    the next chunk launches, so it runs the three phases once per chunk.
+    ``call``, when given, tags every span with the caller's answer number.
     """
     if kind not in CAPABILITIES:
         raise ValueError(f"unknown workload kind {kind!r}")
@@ -1170,21 +1173,26 @@ def run_workload(
     obs.counter("engine.chunks_launched").add(plan.n_chunks)
     obs.gauge("engine.peak_wedge_buffer").set(plan.peak_buffer)
 
-    def launch(fn, chunk, tally, *extra):
+    def launch(fn, chunk, tally, *extra, to_host=True):
         """Enqueue one chunk's copies and kernels, and count them.
 
-        The partial's copy to the host is enqueued too, so that it runs
-        as soon as the chunk is done, under the later chunks' compute,
-        and the fold finds it on the host.
+        Unless ``to_host`` is false, the partial's copy to the host is
+        enqueued too, so that it runs as soon as the chunk is done, under
+        the later chunks' compute, and the fold finds it on the host.
         """
         with obs.span("engine.chunk", cat="engine", args={**ids, **_chunk_shape(chunk)}):
             part = fn(adj, chunk, *extra)
-            if isinstance(part, jax.Array):
+            if to_host and isinstance(part, jax.Array):
                 part.copy_to_host_async()
         tally["chunks"] += 1
         tally["h2d_bytes"] += _upload_bytes(kind, chunk)
         if isinstance(chunk, PanelChunk):
             tally["slots"] = tally.get("slots", 0) + 2 * len(chunk.u) * chunk.width
+            if kind == "support":
+                # support_scatter's adds: base, wu arm and wv closure lanes a row
+                lanes = len(chunk.u) * (1 + 2 * chunk.width)
+                tally["scatter_lanes"] = tally.get("scatter_lanes", 0) + lanes
+                obs.counter("engine.scatter_lanes").add(lanes)
         return part
 
     def done(value):
@@ -1207,16 +1215,35 @@ def run_workload(
         timings["fold"] = sp.seconds
         return done(total)
 
-    if kind == "per_node":
-        fn = backend.per_node_chunk
-        m = adj.row_offsets.shape[0] - 1 if n_out is None else n_out
-    else:
-        fn, m = backend.support_chunk, int(work.src_host.shape[0])
+    if kind == "support":
+        # an edge's support is below the vertex count, so the chunks'
+        # int32 partials add up on the device: one wait and one fold
+        m = int(work.src_host.shape[0])
+        tally = {"chunks": 0, "h2d_bytes": 0}
+        total = jnp.zeros((m,), jnp.int32)
+        with obs.span("engine.dispatch", cat="engine", args=ids) as sp:
+            for chunk in plan.chunks:
+                part = launch(backend.support_chunk, chunk, tally, m, to_host=False)
+                total = _add_partial(total, part)
+            sp.set(**tally)
+        timings["dispatch"] = sp.seconds
+        with obs.span("engine.wait", cat="engine", args=ids) as sp:
+            jax.block_until_ready(total)
+        timings["wait"] = sp.seconds
+        with obs.span("engine.fold", cat="engine", args=ids) as sp:
+            if san is not None:
+                san.check_partial(total, kind=kind, context="sum of the chunks")
+            out = np.asarray(total, dtype=np.int64)
+            sp.set(bytes=int(total.nbytes))
+        timings["fold"] = sp.seconds
+        return done(out)
+
+    m = adj.row_offsets.shape[0] - 1 if n_out is None else n_out
     out = np.zeros((m,), np.int64)
     for i, chunk in enumerate(plan.chunks):
         tally = {"chunks": 0, "h2d_bytes": 0}
         with obs.span("engine.dispatch", cat="engine", args=ids) as sp:
-            part = launch(fn, chunk, tally, m)
+            part = launch(backend.per_node_chunk, chunk, tally, m)
             sp.set(**tally)
         timings["dispatch"] += sp.seconds
         with obs.span("engine.wait", cat="engine", args=ids) as sp:
@@ -1229,6 +1256,11 @@ def run_workload(
             sp.set(bytes=int(part.nbytes))
         timings["fold"] += sp.seconds
     return done(out)
+
+
+@jax.jit
+def _add_partial(total, part):
+    return total + part
 
 
 def _chunk_shape(chunk) -> dict:

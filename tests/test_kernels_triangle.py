@@ -15,7 +15,7 @@ except ImportError:  # container image has no hypothesis; use the local stub
     from _hypothesis_stub import given, settings, st
 
 from repro.core import bucketize_edges, count_triangles, gather_panels, preprocess
-from repro.core.count import gather_elements, gather_windows
+from repro.core.count import add_elements, add_windows, gather_elements, gather_windows
 from repro.kernels.triangle_count import (
     intersect_count_pallas,
     intersect_per_node_pallas,
@@ -182,6 +182,50 @@ def test_window_gather_matches_element_gather_on_graph(small_graphs):
             np.testing.assert_array_equal(
                 np.asarray(gather_windows(*args, width)),
                 np.asarray(gather_elements(*args, width)))
+
+
+def _added(m, base, rows):
+    out = np.zeros(m, np.int64)
+    for s, row in zip(base, rows):
+        n = min(len(row), m - s)
+        out[s : s + n] += row[:n]
+    return out
+
+
+@pytest.mark.parametrize("width", [16, 64, 256, 1024])
+@pytest.mark.parametrize("form", [add_elements, add_windows], ids=["elements", "windows"])
+def test_window_add_matches_slices(form, width, rng):
+    """Both support-scatter adds put ``rows[i]`` at ``out[base[i]:]``,
+    summing where windows overlap: every lane shift 0..127, repeated
+    bases, and windows that run past ``out``'s end with zero lanes
+    there."""
+    m = 2 * width + 3 * 128 + 77  # not a multiple of 128
+    shifted = 128 + np.arange(128)
+    tail = np.arange(m - width - 130, m + 1)
+    base = np.concatenate([shifted, shifted, tail, [0, 0]])
+    rows = rng.integers(0, 1000, (base.size, width)).astype(np.int32)
+    room = m - base
+    rows[np.arange(width)[None, :] >= room[:, None]] = 0
+    start = rng.integers(0, 50, m).astype(np.int32)
+    got = jax.jit(form)(jnp.asarray(start), jnp.asarray(base, jnp.int32), jnp.asarray(rows))
+    np.testing.assert_array_equal(np.asarray(got), start + _added(m, base, rows))
+
+
+def test_window_add_matches_element_add_on_graph(small_graphs, rng):
+    """The TPU's support-scatter add and the CPU's agree on every bucket
+    of a skewed graph's real row offsets, at the real edge count."""
+    e = small_graphs["kron"]
+    csr = preprocess(jnp.asarray(e), n_nodes=int(e.max()) + 1)
+    m = int(csr.col.shape[0])
+    for width, idx in bucketize_edges(csr).items():
+        for ends in (csr.src, csr.col):
+            node = ends[jnp.asarray(idx)]
+            lane = np.arange(width)[None, :]
+            rows = rng.integers(0, 100, (node.shape[0], width)).astype(np.int32)
+            rows[lane >= np.asarray(csr.out_degree[node])[:, None]] = 0
+            args = (jnp.zeros((m,), jnp.int32), csr.row_offsets[node], jnp.asarray(rows))
+            np.testing.assert_array_equal(
+                np.asarray(add_windows(*args)), np.asarray(add_elements(*args)))
 
 
 def test_empty_rows():

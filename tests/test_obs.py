@@ -468,6 +468,32 @@ def test_dispatch_counters_match_the_plan():
     assert args["engine.plan"]["reused"] == 0
 
 
+def test_support_chunks_count_their_scatter_lanes():
+    from repro.core import TriangleCounter
+    from repro.core.engine import PanelBackend, workload_from_csr
+
+    csr = _panel_csr()
+    chunks = list(PanelBackend().plan(workload_from_csr(csr), 1 << 10).chunks)
+    want = sum(len(c.u) * (1 + c.width + c.width) for c in chunks)
+    assert {c.width for c in chunks} == {16, 64}
+    tc = TriangleCounter(method="panel", max_wedge_chunk=1 << 10)
+    with obs.tracing() as t:
+        tc.edge_support(csr)
+    (dispatch,) = [e.get("args", {}) for e in t.events if e["name"] == "engine.dispatch"]
+    # one dispatch launches every chunk: base, arm and closure lanes, padded rows included
+    assert dispatch["chunks"] == len(chunks) == len(
+        [e for e in t.events if e["name"] == "engine.chunk"])
+    assert dispatch["scatter_lanes"] == want
+    assert obs.metrics_snapshot()["counters"]["engine.scatter_lanes"] == want
+    # a count of the same graph carries neither the arg nor the counter
+    obs.reset_metrics()
+    with obs.tracing() as t:
+        tc.count(csr)
+    (args,) = [e.get("args", {}) for e in t.events if e["name"] == "engine.dispatch"]
+    assert "scatter_lanes" not in args and args["slots"] > 0
+    assert "engine.scatter_lanes" not in obs.metrics_snapshot()["counters"]
+
+
 @pytest.mark.parametrize("kind", ["per_node", "edge_support"])
 def test_per_chunk_phases_of_per_node_and_support(kind, small_graphs):
     from repro.core import TriangleCounter
@@ -480,9 +506,12 @@ def test_per_chunk_phases_of_per_node_and_support(kind, small_graphs):
     n = tc.last_stats.n_chunks
     assert n > 1
     names = [e["name"] for e in t.events]
-    # per-node and support fold each chunk before the next one launches
-    for phase in ("dispatch", "chunk", "wait", "fold"):
-        assert names.count(f"engine.{phase}") == n, phase
+    assert names.count("engine.chunk") == n
+    # per-node folds each chunk before the next one launches; support adds
+    # its chunks up on the device, waits once and folds once
+    per_answer = n if kind == "per_node" else 1
+    for phase in ("dispatch", "wait", "fold"):
+        assert names.count(f"engine.{phase}") == per_answer, phase
     es = tc.last_stats
     assert tuple(es.timings) == PHASES
     for phase in ("dispatch", "wait", "fold"):

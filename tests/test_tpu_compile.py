@@ -24,6 +24,7 @@ from repro.core.engine import (
     DEFAULT_WIDTHS,
     chunk_count_kernel,
     chunk_per_node_kernel,
+    support_scatter,
 )
 from repro.core.count import gather_panels_arrays
 from repro.core.tuning import candidate_tiles
@@ -121,3 +122,20 @@ def test_panel_gather_compiles_at_scale21(one_chip, width):
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < (2 << 30), mem
+
+
+@pytest.mark.parametrize("width", (16, 64, 256))
+def test_support_scatter_compiles_at_kron16(one_chip, width):
+    """The per-edge support scatter of one budget-sized chunk into a
+    Kronecker-16 graph's 909,785 edges, in its TPU form: the arm and
+    closure rows added as whole 128-lane rows, not single elements."""
+    rows, n_nodes, m_out = min(WEDGE_BUDGET // width, 1 << 16), 1 << 16, 909_785
+    lowered = support_scatter.lower(
+        _sds((rows,), one_chip), _sds((rows,), one_chip), _sds((rows,), one_chip),
+        _sds((n_nodes + 1,), one_chip), _sds((rows,), one_chip),
+        _sds((rows, width), one_chip), _sds((rows, width), one_chip), m_out=m_out,
+    )
+    assert "update_window_dims = [2]" in lowered.as_text()
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < (1 << 30), mem
